@@ -15,6 +15,9 @@ trend, not a speed win. Run standalone with
 
 or as part of `benchmarks.run --only exec`. The measurement runs in a
 subprocess so the 8-device XLA flag does not leak into sibling benchmarks.
+The subprocess runs with JAX_PLATFORMS=cpu: it emulates its mesh on host
+CPU devices by design, and on a machine with a TPU the parent, which has
+imported JAX, holds the chip.
 """
 from __future__ import annotations
 
@@ -32,13 +35,14 @@ import json, time
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import plans, topology
 from repro.core.gentree import gentree
 from repro.core.lower import lower_plan
 
 N, SIZE = 8, 1 << 16
-mesh = jax.make_mesh((N,), ("x",))
+mesh = make_mesh((N,), ("x",))
 x = jax.random.normal(jax.random.PRNGKey(0), (N, SIZE), jnp.float32)
 
 
@@ -80,6 +84,7 @@ def run() -> dict:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _DRIVER], env=env,
                          capture_output=True, text=True, timeout=900)
     if out.returncode != 0:

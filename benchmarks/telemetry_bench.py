@@ -82,8 +82,7 @@ def run() -> dict:
     from repro.runtime.telemetry import default_telemetry
     run_training(TrainConfig(arch="stablelm-12b", steps=SIM_STEPS,
                              seq_len=32, global_batch=4, engine="manual",
-                             sync="plan", log_every=10 ** 6),
-                 smoke=True, on_log=lambda *a, **k: None)
+                             sync="plan", log_every=10 ** 6), on_log=lambda *a, **k: None)
     ring = default_telemetry().ring("train/step")
     assert ring.count >= SIM_STEPS, "trainer did not feed the step ring"
     step_s = ring.percentile(50.0)               # median: jit-proof
@@ -159,13 +158,13 @@ def run() -> dict:
     old_tracer = set_default_tracer(Tracer(enabled=False))
     try:
         set_default_telemetry(Telemetry())
-        run_training(tcfg, smoke=True, on_log=lambda *a, **k: None)
+        run_training(tcfg, on_log=lambda *a, **k: None)
         untraced_s = default_telemetry().ring("train/step").percentile(50.0)
 
         traced_tracer = Tracer(enabled=True)
         set_default_tracer(traced_tracer)
         set_default_telemetry(Telemetry())
-        run_training(tcfg, smoke=True, on_log=lambda *a, **k: None)
+        run_training(tcfg, on_log=lambda *a, **k: None)
         traced_s = default_telemetry().ring("train/step").percentile(50.0)
 
         traced_tracer.export_chrome("BENCH_trace.json")

@@ -13,6 +13,7 @@ import tempfile
 
 import jax
 
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, run_training
 
 
@@ -21,7 +22,7 @@ def main():
     devs = jax.devices()
     print(f"{len(devs)} devices available")
 
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh8 = make_mesh((8, 1), ("data", "model"))
     tc = dict(arch="rwkv6-1.6b", seq_len=64, global_batch=8, lr=1e-3,
               ckpt_dir=ckpt, ckpt_every=10, log_every=5)
     print("— phase 1: 8-device mesh, steps 0–19 —")
@@ -30,7 +31,7 @@ def main():
     # "lose a pod": continue on half the devices. The checkpoint is
     # device-agnostic (numpy), so restore just re-shards onto the new
     # mesh (runtime.elastic_remesh under the hood of the restore path).
-    mesh4 = jax.make_mesh((4, 1), ("data", "model"))
+    mesh4 = make_mesh((4, 1), ("data", "model"))
     print("— phase 2: restored onto a 4-device mesh, steps 20–39 —")
     out2 = run_training(TrainConfig(**tc, steps=40), mesh=mesh4)
 

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.cost_model import PRECISIONS
 from repro.core.sync import SyncConfig, sync_gradients
 from repro.planner.service import default_service
@@ -32,7 +33,7 @@ from repro.planner.service import default_service
 def main():
     n = 8
     axes = [("data", n)]
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     cfg = SyncConfig(strategy="plan", precision="fp8", tolerance=0.3)
 
     key = jax.random.PRNGKey(0)

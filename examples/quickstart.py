@@ -15,7 +15,8 @@ os.environ.setdefault("XLA_FLAGS",
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core import cost_model as cm
@@ -51,7 +52,7 @@ print(f"\nGenTree on 12-server switch picks: {dec.algo} {dec.factors} "
       f"(predicted {result.predicted_time:.3f}s)")
 
 # -- 4. run that plan as a JAX collective schedule --------------------------
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 1000))
 
 f = shard_map(

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.sync import SyncConfig, sync_gradients
 from repro.runtime.metrics import default_metrics
 from repro.runtime.trace import default_tracer
@@ -43,7 +44,7 @@ def main():
 
     n = 8
     axes = [("data", n)]
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     # bucket_bytes pinned below the pytree size so the step really runs
     # multiple buckets and the RS(k+1)/AG(k) overlap shows in the trace
     cfg = SyncConfig(strategy="plan", bucket_bytes=8192, pipeline=True)

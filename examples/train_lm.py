@@ -15,6 +15,7 @@ import dataclasses
 import jax
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, run_training
 from repro.models.config import ModelConfig
 
@@ -48,18 +49,19 @@ def main():
     import sys
     sys.modules["repro.configs.lm_100m"] = mod
 
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     n = mod.CONFIG.params_count()
     print(f"training lm-100m ({n/1e6:.0f}M params) on "
           f"{len(jax.devices())} devices, engine={args.engine}, "
           f"sync={args.sync}")
     out = run_training(
-        TrainConfig(arch="lm-100m", steps=args.steps, seq_len=128,
+        TrainConfig(arch="lm-100m", layers=mod.CONFIG.n_layers,
+                    steps=args.steps, seq_len=128,
                     global_batch=max(2, len(jax.devices())),
                     engine=args.engine, sync=args.sync,
                     lr=6e-4, ckpt_dir=args.ckpt_dir, ckpt_every=100,
                     log_every=20),
-        mesh=mesh, smoke=False)
+        mesh=mesh)
     losses = out["losses"]
     print(f"loss: {losses[0]:.3f} → {losses[-1]:.3f} over "
           f"{len(losses)} steps")

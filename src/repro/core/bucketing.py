@@ -486,11 +486,17 @@ def zero3_gather_bucketed(shards, specs, plan, bucket_bytes: int, n: int
     `shard_params_zero3` layout); `specs[ℓ] = (shape, dtype)` describes
     the full leaf. Same-dtype shards concatenate into one row per bucket,
     padded to the schedule's blocks-per-shard multiple, and ONE
-    `all_gather` launch per bucket reassembles the (n, ΣC) matrix whose
-    columns split back into the full leaves — per-leaf α collapses to
-    per-bucket α. The shard cap is `bucket_bytes / n`: the gather
-    launch reassembles n× its input, so this keeps the moved data per
-    launch at the bucket size the GenModel sweep actually priced."""
+    `all_gather` launch per bucket reassembles the n rows end to end;
+    each leaf is the concatenation of its n contiguous pieces — per-leaf
+    α collapses to per-bucket α. The shard cap is `bucket_bytes / n`:
+    the gather launch reassembles n× its input, so this keeps the moved
+    data per launch at the bucket size the GenModel sweep actually
+    priced.
+
+    The pieces are 1-D slices, not columns of an (n, Σchunk) matrix: on
+    a TPU a matrix with n rows pads its sublane dimension (8 rows for
+    f32, 16 for bf16) and its strided column slices relayout the whole
+    bucket, which multiplied the step's compile time."""
     import jax.numpy as jnp
 
     cs = plan.schedule
@@ -504,18 +510,20 @@ def zero3_gather_bucketed(shards, specs, plan, bucket_bytes: int, n: int
     for bi, bk in enumerate(buckets):
         row = jnp.concatenate([shards[i].reshape(-1) for i in bk.indices]) \
             if len(bk.indices) > 1 else shards[bk.indices[0]].reshape(-1)
-        ncols = row.size
         row = _pad_to(row, k)
+        width = row.size
         with tracer.span("bucket/zero3_ag", bucket=bi,
                          leaves=len(bk.indices)):
-            mat = cs.all_gather(row, plan.axis).reshape(n, -1)[:, :ncols]
+            flat = cs.all_gather(row, plan.axis)
         off = 0
         for i, c in zip(bk.indices, bk.sizes):
             shape, dtype = specs[i]
             count = 1
             for s in shape:
                 count *= s
-            out[i] = (mat[:, off:off + c].reshape(-1)[:count]
+            pieces = [flat[j * width + off:j * width + off + c]
+                      for j in range(n)]
+            out[i] = (jnp.concatenate(pieces)[:count]
                       .reshape(shape).astype(dtype))
             off += c
     for i, (shape, dtype) in enumerate(specs):
@@ -528,9 +536,10 @@ def zero3_scatter_bucketed(fulls, plan, bucket_bytes: int, n: int,
                            reverse: bool = False) -> list:
     """Bucketed gradient ReduceScatter (inverse layout of
     `zero3_gather_bucketed`): each full leaf pads to a multiple of `n`
-    and contributes its (n, chunk_ℓ) rows as columns of the bucket
-    matrix; ONE `reduce_scatter` launch per bucket returns row i — the
-    concatenation of every member leaf's canonical shard i.
+    and splits into n contiguous chunks; the bucket vector holds, for
+    j = 0..n-1, chunk j of every member leaf, so ONE `reduce_scatter`
+    launch per bucket returns segment i — the concatenation of every
+    member leaf's canonical shard i.
 
     `reverse=True` issues buckets in reverse-layer readiness order
     (DESIGN.md §15): backward materializes the LAST bucket's gradients
@@ -550,17 +559,18 @@ def zero3_scatter_bucketed(fulls, plan, bucket_bytes: int, n: int,
     if reverse:
         issue.reverse()
     for bi, bk in issue:
-        mats = [_pad_to(fulls[i].reshape(-1), n).reshape(n, -1)
-                for i in bk.indices]
-        mat = mats[0] if len(mats) == 1 else jnp.concatenate(mats, axis=1)
-        ncols = mat.shape[1]
-        pad = (-ncols) % k
-        if pad:
-            mat = jnp.concatenate(
-                [mat, jnp.zeros((n, pad), mat.dtype)], axis=1)
+        vecs = [_pad_to(fulls[i].reshape(-1), n) for i in bk.indices]
+        width = sum(chunks[i] for i in bk.indices)
+        pad = (-width) % k
+        pieces = []
+        for j in range(n):
+            for i, v in zip(bk.indices, vecs):
+                pieces.append(v[j * chunks[i]:(j + 1) * chunks[i]])
+            if pad:
+                pieces.append(jnp.zeros((pad,), vecs[0].dtype))
         with tracer.span("bucket/zero3_rs", bucket=bi,
                          leaves=len(bk.indices)):
-            shard = cs.reduce_scatter(mat.reshape(-1), plan.axis)
+            shard = cs.reduce_scatter(jnp.concatenate(pieces), plan.axis)
         off = 0
         for i in bk.indices:
             out[i] = shard[off:off + chunks[i]]

@@ -41,6 +41,7 @@ the no-JAX reference used by the hypothesis equivalence suite.
 """
 from __future__ import annotations
 
+import sys
 import weakref
 from dataclasses import dataclass, field
 from typing import Callable, Mapping, Sequence
@@ -1136,6 +1137,8 @@ class GuardedSchedule:
             tele.remeasure(reason, info)
 
     def _note_fallback(self, what: str, err) -> None:
+        print(f"fallback: planned {self.inner.plan_name} {what} -> flat "
+              f"lax collective ({err!r})", file=sys.stderr, flush=True)
         self.stats["fallbacks"] += 1
         self._demoted = True
         self._metrics().counter(
@@ -1155,6 +1158,9 @@ class GuardedSchedule:
         return self._full
 
     def _note_wire_fallback(self, what: str, err) -> None:
+        print(f"fallback: compressed {self.inner.wire.name} "
+              f"{self.inner.plan_name} {what} -> full precision ({err!r})",
+              file=sys.stderr, flush=True)
         self.stats["wire_fallbacks"] += 1
         self._wire_demoted = True
         self._metrics().counter(
@@ -1376,6 +1382,13 @@ def reprobe_guards(reason: str = "health_restore") -> int:
         default_tracer().instant("guard/reprobe", reason=reason,
                                  cleared=cleared)
     return cleared
+
+
+def guard_stats() -> list[tuple[str, dict]]:
+    """(plan name, stats) of every live guard: a run that must not have
+    fallen back checks `fallbacks`, `wire_fallbacks`, `demoted_launches`
+    and `timeouts` here."""
+    return [(g.inner.plan_name, dict(g.stats)) for g in list(_GUARD_REGISTRY)]
 
 
 def guard_schedule(schedule, *, telemetry=None, policy=None):

@@ -10,7 +10,13 @@ requantizes the output, all in a single memory pass — the δ-optimal shape
 of `kernels.fused_reduce` carried over to the compressed domain.
 
 Layouts mirror `fused_reduce`: payloads are (W, L) with L tiled along the
-lane axis; scales are (W, nt) with nt = ceil(L / tile). A scale of 0 marks
+lane axis; scales are (W, nt) with nt = ceil(L / tile). Each grid step
+covers SCALE_BLOCK tiles of every row: a (W, SCALE_BLOCK·tile) payload
+block and its (W, SCALE_BLOCK) scale block, both aligned to the TPU's
+(8, 128) tiling (rows of at most SCALE_BLOCK tiles are one whole block).
+The last block of a longer row may run past its end: every tile carries
+its own scale, so the tiles past the end compute garbage that Pallas
+drops on write, and no operand is padded or output sliced for it. A scale of 0 marks
 an all-zero (or masked) tile — dequantization multiplies by the scale, so
 such tiles decode to exactly 0 regardless of payload bits; the schedule
 executor uses this to neutralize masked ppermute rows for free.
@@ -28,6 +34,9 @@ from jax.experimental import pallas as pl
 from .fused_reduce import pad_lanes
 
 QUANT_TILE = 128  # lanes per f32 scale; matches Precision.scale_block
+# scales per grid step: one lane row of the TPU's (8, 128) tiling, so a
+# scale block is tiling-aligned (a (W, 1) block is not)
+SCALE_BLOCK = 128
 
 # Symmetric full-scale magnitude per wire dtype.
 WIRE_QMAX = {
@@ -44,16 +53,15 @@ def wire_dtype(wire: str) -> jnp.dtype:
 
 
 def _encode(vals: jax.Array, wire: str) -> tuple[jax.Array, jax.Array]:
-    """vals (..., tile) f32 → (q (..., tile) wire, scale (..., 1) f32).
+    """vals (..., tile) f32 → (q (..., tile) wire, scale (...) f32).
 
-    Shared by the Pallas kernels (per-block) and the jnp oracle (reshaped).
     scale = amax/qmax, stored as 0 for all-zero tiles so dequant is exact 0.
     """
     qmax = WIRE_QMAX[wire]
-    amax = jnp.max(jnp.abs(vals), axis=-1, keepdims=True)
+    amax = jnp.max(jnp.abs(vals), axis=-1)
     scale = amax / qmax
     safe = jnp.where(scale > 0.0, scale, 1.0)
-    y = vals / safe
+    y = vals / safe[..., None]
     if wire == "int8":
         y = jnp.clip(jnp.round(y), -qmax, qmax)
     else:
@@ -61,9 +69,28 @@ def _encode(vals: jax.Array, wire: str) -> tuple[jax.Array, jax.Array]:
     return y.astype(jnp.dtype(wire)), jnp.where(amax > 0.0, scale, 0.0)
 
 
-def _quantize_kernel(x_ref, q_ref, s_ref, *, wire: str):
-    q, s = _encode(x_ref[...].astype(jnp.float32), wire)
-    q_ref[...] = q
+def _blocking(nt: int) -> tuple[int, int]:
+    """(tiles per grid step, grid steps) for nt tiles of a row.
+
+    A scale block is a whole lane row of the (8, 128) layout — or, for
+    rows of at most SCALE_BLOCK tiles, the whole array. The last step of
+    a longer row is ragged (see the module docstring)."""
+    tb = min(nt, SCALE_BLOCK)
+    return tb, pl.cdiv(nt, tb)
+
+
+def _dequant_tiles(q: jax.Array, s: jax.Array, tile: int) -> jax.Array:
+    """q (R, tb·tile) wire, s (R, tb) → (R, tb, tile) f32. A zero scale
+    decodes to exactly 0, whatever the payload bits."""
+    t = q.reshape(q.shape[0], -1, tile).astype(jnp.float32)
+    s = s[:, :, None]
+    return jnp.where(s != 0.0, t * s, 0.0)
+
+
+def _quantize_kernel(x_ref, q_ref, s_ref, *, wire: str, tile: int):
+    x = x_ref[...].astype(jnp.float32)
+    q, s = _encode(x.reshape(x.shape[0], -1, tile), wire)
+    q_ref[...] = q.reshape(x.shape)
     s_ref[...] = s
 
 
@@ -72,26 +99,30 @@ def quantize(x: jax.Array, wire: str = "float8_e4m3fn", *,
              ) -> tuple[jax.Array, jax.Array]:
     """(W, L) f32 → (q (W, Lp) wire, scales (W, nt) f32), Lp = tile-padded L.
 
-    One memory pass: each (W, tile) block is read once, its abs-max scale
-    and encoded payload written once.
+    One memory pass: each (W, tb·tile) block is read once, its tb abs-max
+    scales and encoded payload written once.
     """
     W, L = x.shape
+    nt = -(-L // tile)
+    tb, steps = _blocking(nt)
+    # only a partial last tile is padded: its zeros are part of the tile
+    # the scale is taken over
     x = pad_lanes(x.astype(jnp.float32), tile)
-    nt = x.shape[1] // tile
     return pl.pallas_call(
-        functools.partial(_quantize_kernel, wire=wire),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((W, tile), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((W, tile), lambda i: (0, i)),
-                   pl.BlockSpec((W, 1), lambda i: (0, i))],
+        functools.partial(_quantize_kernel, wire=wire, tile=tile),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((W, tb * tile), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((W, tb * tile), lambda i: (0, i)),
+                   pl.BlockSpec((W, tb), lambda i: (0, i))],
         out_shape=[jax.ShapeDtypeStruct((W, nt * tile), wire_dtype(wire)),
                    jax.ShapeDtypeStruct((W, nt), jnp.float32)],
         interpret=interpret,
     )(x)
 
 
-def _dequantize_kernel(q_ref, s_ref, out_ref):
-    out_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
+def _dequantize_kernel(q_ref, s_ref, out_ref, *, tile: int):
+    out_ref[...] = _dequant_tiles(q_ref[...], s_ref[...], tile).reshape(
+        out_ref.shape)
 
 
 def dequantize(q: jax.Array, scales: jax.Array, *,
@@ -100,26 +131,29 @@ def dequantize(q: jax.Array, scales: jax.Array, *,
     """(q (W, Lp) wire, scales (W, nt)) → (W, out_len or Lp) f32."""
     W, Lp = q.shape
     nt = Lp // tile
+    tb, steps = _blocking(nt)
     out = pl.pallas_call(
-        _dequantize_kernel,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((W, tile), lambda i: (0, i)),
-                  pl.BlockSpec((W, 1), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((W, tile), lambda i: (0, i)),
+        functools.partial(_dequantize_kernel, tile=tile),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((W, tb * tile), lambda i: (0, i)),
+                  pl.BlockSpec((W, tb), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((W, tb * tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((W, Lp), jnp.float32),
         interpret=interpret,
     )(q, scales)
     return out if out_len is None or out_len == Lp else out[:, :out_len]
 
 
-def _quant_reduce_kernel(q_ref, s_ref, out_ref):
-    # q (K, tile) wire, s (K, 1) f32: dequant + x-ary add, one VMEM pass.
-    out_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[...]).sum(axis=0)
+def _quant_reduce_kernel(q_ref, s_ref, out_ref, *, tile: int):
+    # q (K, tb·tile) wire, s (K, tb) f32: dequant + K-ary add, one VMEM pass
+    deq = _dequant_tiles(q_ref[...], s_ref[...], tile)
+    out_ref[...] = deq.reshape(deq.shape[0], -1).sum(axis=0)
 
 
-def _quant_reduce_own_kernel(q_ref, s_ref, own_ref, out_ref):
-    acc = (q_ref[...].astype(jnp.float32) * s_ref[...]).sum(axis=0)
-    out_ref[...] = acc + own_ref[...].astype(jnp.float32)
+def _quant_reduce_own_kernel(q_ref, s_ref, own_ref, out_ref, *, tile: int):
+    deq = _dequant_tiles(q_ref[...], s_ref[...], tile)
+    out_ref[...] = (deq.reshape(deq.shape[0], -1).sum(axis=0)
+                    + own_ref[...].astype(jnp.float32))
 
 
 def quant_reduce(q: jax.Array, scales: jax.Array,
@@ -135,32 +169,36 @@ def quant_reduce(q: jax.Array, scales: jax.Array,
     """
     K, Lp = q.shape
     nt = Lp // tile
-    common = dict(
-        grid=(nt,),
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
+    tb, steps = _blocking(nt)
+    q_spec = pl.BlockSpec((K, tb * tile), lambda i: (0, i))
+    s_spec = pl.BlockSpec((K, tb), lambda i: (0, i))
+    row_spec = pl.BlockSpec((tb * tile,), lambda i: (i,))
+    args = [q, scales]
+    if own is None:
+        kernel = _quant_reduce_kernel
+    else:
+        kernel = _quant_reduce_own_kernel
+        # the resident partial may stop at the row's real length; its
+        # tile padding adds nothing
+        args.append(pad_lanes(own.astype(jnp.float32), tile))
+    out = pl.pallas_call(
+        functools.partial(kernel, tile=tile),
+        grid=(steps,),
+        in_specs=[q_spec, s_spec, row_spec][:len(args)],
+        out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Lp,), jnp.float32),
         interpret=interpret,
-    )
-    q_spec = pl.BlockSpec((K, tile), lambda i: (0, i))
-    s_spec = pl.BlockSpec((K, 1), lambda i: (0, i))
-    if own is None:
-        out = pl.pallas_call(_quant_reduce_kernel,
-                             in_specs=[q_spec, s_spec], **common)(q, scales)
-    else:
-        own = pad_lanes(own.astype(jnp.float32), tile)
-        out = pl.pallas_call(
-            _quant_reduce_own_kernel,
-            in_specs=[q_spec, s_spec, pl.BlockSpec((tile,), lambda i: (i,))],
-            **common)(q, scales, own)
-    return out if out_len is None or out_len == Lp else out[:out_len]
+    )(*args)
+    n = Lp if out_len is None else out_len
+    return out if n == out.shape[0] else out[:n]
 
 
 def _quant_reduce_requant_kernel(q_ref, s_ref, qout_ref, sout_ref, *,
-                                 wire: str):
-    acc = (q_ref[...].astype(jnp.float32) * s_ref[...]).sum(axis=0)
-    qo, so = _encode(acc[None, :], wire)
-    qout_ref[...] = qo[0]
-    sout_ref[...] = so[0]
+                                 wire: str, tile: int):
+    acc = _dequant_tiles(q_ref[...], s_ref[...], tile).sum(axis=0)
+    qo, so = _encode(acc, wire)                  # (tb, tile), (tb,)
+    qout_ref[...] = qo
+    sout_ref[...] = so[None, :]
 
 
 def quant_reduce_requant(q: jax.Array, scales: jax.Array,
@@ -173,14 +211,17 @@ def quant_reduce_requant(q: jax.Array, scales: jax.Array,
     precision resident partial)."""
     K, Lp = q.shape
     nt = Lp // tile
-    return pl.pallas_call(
-        functools.partial(_quant_reduce_requant_kernel, wire=wire),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((K, tile), lambda i: (0, i)),
-                  pl.BlockSpec((K, 1), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Lp,), wire_dtype(wire)),
-                   jax.ShapeDtypeStruct((nt,), jnp.float32)],
+    tb, steps = _blocking(nt)
+    qo, so = pl.pallas_call(
+        functools.partial(_quant_reduce_requant_kernel, wire=wire,
+                          tile=tile),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((K, tb * tile), lambda i: (0, i)),
+                  pl.BlockSpec((K, tb), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((tb, tile), lambda i: (i, 0)),
+                   pl.BlockSpec((1, tb), lambda i: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((nt, tile), wire_dtype(wire)),
+                   jax.ShapeDtypeStruct((1, nt), jnp.float32)],
         interpret=interpret,
     )(q, scales)
+    return qo.reshape(-1), so.reshape(-1)

@@ -23,6 +23,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _dot(a, b):
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
                 s_scr, *, nc: int, chunk: int):
     ci = pl.program_id(1)
@@ -37,20 +42,23 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
     lw = lw_ref[0].astype(jnp.float32)          # (C, K) ≤ 0
     u = u_ref[0].astype(jnp.float32)            # (1, K) broadcast row
 
-    linc = jnp.cumsum(lw, axis=0)               # inclusive Λ
+    c = rc.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # inclusive Λ as a product with the lower-triangular ones matrix:
+    # Mosaic has no cumsum lowering, and the MXU does this in one pass
+    linc = _dot((row >= col).astype(jnp.float32), lw)
     lexc = linc - lw                            # exclusive
     s = s_scr[...]                              # (K, V)
 
     # state contribution: r_t decayed by Λ_{<t}
-    o1 = (rc * jnp.exp(lexc)) @ s               # (C, V)
-    # intra-chunk pairs s < t: exponent lexc_t − linc_s ≤ 0
-    expo = lexc[:, None, :] - linc[None, :, :]  # (C, C, K)
-    c = rc.shape[0]
-    tmask = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    pair = jnp.where(tmask[:, :, None], jnp.exp(expo), 0.0)
-    att = jnp.einsum("tk,sk,tsk->ts", rc, kc, pair)
-    o2 = att @ vc                               # (C, V)
+    o1 = _dot(rc * jnp.exp(lexc), s)            # (C, V)
+    # intra-chunk pairs s < t: exponent lexc_t − linc_s ≤ 0. The clamp
+    # only touches the masked s ≥ t entries, keeping their exp finite.
+    expo = jnp.minimum(lexc[:, None, :] - linc[None, :, :], 0.0)
+    pair = rc[:, None, :] * kc[None, :, :] * jnp.exp(expo)   # (C, C, K)
+    att = jnp.where(row > col, jnp.sum(pair, axis=-1), 0.0)
+    o2 = _dot(att, vc)                          # (C, V)
     # bonus (current token)
     bonus = jnp.sum(rc * kc * u, axis=-1, keepdims=True)
     o3 = bonus * vc
@@ -59,7 +67,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
     # state update: decay by the whole chunk, add k_t (decayed to end) v_t
     ltot = linc[-1:, :]                         # (1, K)
     s_scr[...] = jnp.exp(ltot).T * s + \
-        (kc * jnp.exp(ltot - linc)).T @ vc
+        _dot((kc * jnp.exp(ltot - linc)).T, vc)
 
     @pl.when(ci == nc - 1)
     def _fini():

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.config import smoke_config
 from repro.models.registry import build
 from repro.runtime.metrics import default_metrics
@@ -72,8 +73,8 @@ def serve(sc: ServeConfig, smoke: bool = True, on_log=print) -> dict:
         on_log(f"planner: decode AllReduce executes {tp_exec.algo} plan "
                f"({sched.describe()})")
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import shard_map
-        mesh = jax.make_mesh((n_dev,), ("model",))
+        from jax import shard_map
+        mesh = make_mesh((n_dev,), ("model",))
         probe = jax.random.normal(
             jax.random.PRNGKey(2), (n_dev, sc.batch * cfg.d_model))
         # jitted: an un-jitted shard_map re-traces (and re-dispatches
@@ -178,6 +179,8 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=32)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     serve(ServeConfig(arch=args.arch, batch=args.batch,
                       max_new=args.max_new))
 

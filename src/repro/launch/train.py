@@ -15,14 +15,15 @@ Two engines:
   collective schedule, the engine executes it.
 
 `python -m repro.launch.train --arch <id> --steps N` runs a reduced-config
-training loop on the local device (examples/tests); full-size configs are
-exercised via launch.dryrun.
+training loop on the local devices (examples/tests); `--layers L` runs the
+config at its published widths with its depth cut to L layers instead.
+Full-size configs are exercised via launch.dryrun.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
+import sys
 from typing import Any, Sequence
 
 import jax
@@ -34,7 +35,18 @@ from repro.core.sync import AxisPlan, SyncConfig, plan_axes_gentree
 from repro.models.registry import ModelAPI
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 from . import sharding as shr
-from .mesh import dp_axes, axis_sizes
+from .compile_cache import enable_compile_cache
+from .mesh import axis_sizes, dp_axes, make_mesh
+
+
+def _note_sync_fallback(what: str, err) -> None:
+    """A planned sync path replaced by a simpler one: counted, and named
+    on stderr, so a run that fell back never passes for one that did not."""
+    from repro.runtime.metrics import default_metrics
+    default_metrics().counter(
+        "sync_fallbacks_total",
+        "planned sync paths replaced by a simpler one").inc()
+    print(f"fallback: {what} ({err!r})", file=sys.stderr, flush=True)
 
 
 # ---------------------------------------------------------------------------
@@ -106,7 +118,9 @@ def shard_params_zero3(params: Any, mesh: Mesh) -> Any:
         if pad:
             flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
         out = flat.reshape(n, -1)
-        return jax.device_put(out, NamedSharding(mesh, P(dp, None)))
+        # P(dp), not P(dp, None): the step's outputs carry the normalized
+        # spec, and an input spec that differs in form recompiles step 1
+        return jax.device_put(out, NamedSharding(mesh, P(dp)))
 
     return jax.tree.map(split, params)
 
@@ -223,7 +237,9 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
                     pipeline=sync.pipeline,
                     precision=getattr(sync, "precision", None),
                     tolerance=getattr(sync, "tolerance", None)))
-        except LoweringError:
+        except LoweringError as e:
+            _note_sync_fallback("bucketed ZeRO-3 halves -> per-leaf "
+                                "collectives", e)
             return None
         cs = bp.axis_plans[0].schedule if bp.axis_plans else None
         if cs is None or not cs.blocks_per_shard:
@@ -257,6 +273,7 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
         if svc is None:
             from repro.planner.service import default_service
             svc = default_service()
+        from repro.core.lower import LoweringError
         try:
             resp = svc.get_family_executable(
                 "all_to_all", ep_axis, ep_n, total_f32_equiv or 1.0,
@@ -266,8 +283,10 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
                 from repro.core.lower import guard_schedule
                 ep_sched = guard_schedule(
                     ep_sched, telemetry=getattr(svc, "telemetry", None))
-        except Exception:
-            ep_sched = None           # lax.all_to_all fallback
+        except LoweringError as e:
+            _note_sync_fallback("planned expert AllToAll -> "
+                                "lax.all_to_all", e)
+            ep_sched = None
 
     def step(state, batch):
         from repro.models import actsharding
@@ -279,6 +298,11 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
                 float(jnp.size(s)) for s in jax.tree.leaves(p_shards)) or 1.0
             bplan = bucket_plan_for()
             plans = None if bplan is not None else plans_for(total_size)
+            from repro.runtime.metrics import default_metrics
+            default_metrics().counter(
+                "zero3_bucketed_traces_total" if bplan is not None
+                else "zero3_per_leaf_traces_total",
+                "ZeRO-3 steps traced on this sync path").inc()
 
             flat_shards = jax.tree.leaves(p_shards)
             if bplan is not None:
@@ -318,7 +342,7 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
             gn = jax.lax.pmean(gn, tuple(a for a, _ in axes))
             return new_p, new_o, loss, gn
 
-        from repro.core.compat import shard_map
+        from jax import shard_map
         spec_shard = jax.tree.map(lambda _: P(dp, None), state["params"])
         spec_opt = {"m": spec_shard, "v": spec_shard, "step": P()}
         bspec = shr.batch_specs(batch, mesh)
@@ -358,7 +382,7 @@ def observe_sync_probe(svc, mesh, axes, size_floats, on_log=print, *,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core.sync import axis_level
 
     out = []
@@ -399,13 +423,17 @@ def observe_sync_probe(svc, mesh, axes, size_floats, on_log=print, *,
                        f"drift {obs['drift']:.2f}"
                        + (", refit" if obs["refit"] else "") + ")")
             except Exception as e:   # advisory — never fail training
-                on_log(f"planner: sync probe for axis {a} skipped ({e!r})")
+                _note_sync_fallback(f"sync probe for axis {a} skipped", e)
     return out
 
 
 @dataclasses.dataclass
 class TrainConfig:
     arch: str = "stablelm-12b"
+    # None: the smoke-shrunk config of the same family (CPU tests).
+    # L: the published config at its published widths, depth cut to L
+    # layers.
+    layers: int | None = None
     steps: int = 50
     seq_len: int = 128
     global_batch: int = 8
@@ -438,7 +466,7 @@ class TrainConfig:
 
 
 def run_training(tc: TrainConfig, mesh: Mesh | None = None,
-                 smoke: bool = True, on_log=print) -> dict:
+                 on_log=print) -> dict:
     from repro.configs import get_config
     from repro.data import DataConfig, SyntheticLM
     from repro.models.config import smoke_config
@@ -446,11 +474,17 @@ def run_training(tc: TrainConfig, mesh: Mesh | None = None,
     from repro.checkpoint import CheckpointManager
     from repro.runtime import FaultTolerantLoop
 
-    cfg = get_config(tc.arch)
-    if smoke:
-        cfg = smoke_config(cfg)
+    published = get_config(tc.arch)
+    cfg = (smoke_config(published) if tc.layers is None
+           else dataclasses.replace(published, n_layers=tc.layers))
     api = build(cfg)
-    mesh = mesh or jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    n_params = sum(math.prod(leaf.shape)
+                   for leaf in jax.tree.leaves(api.params_spec()))
+    on_log(f"model: {cfg.name} [{cfg.family}] d_model={cfg.d_model} "
+           f"d_ff={cfg.d_ff} heads={cfg.n_heads}x{cfg.head_dim} "
+           f"vocab={cfg.vocab} layers={cfg.n_layers}/{published.n_layers} "
+           f"params={n_params:,}")
+    mesh = mesh or make_mesh((len(jax.devices()), 1), ("data", "model"))
 
     dcfg = DataConfig(
         vocab=cfg.vocab, seq_len=tc.seq_len, global_batch=tc.global_batch,
@@ -461,18 +495,39 @@ def run_training(tc: TrainConfig, mesh: Mesh | None = None,
     opt_cfg = AdamWConfig(lr=tc.lr)
 
     params = api.init_params(jax.random.PRNGKey(tc.seed))
-    state = {"params": params, "opt": adamw_init(params)}
-
     if tc.engine == "manual":
-        state = {"params": shard_params_zero3(state["params"], mesh),
-                 "opt": adamw_init(shard_params_zero3(params, mesh))}
+        # drop the unsharded copy before the optimizer state exists: at
+        # published widths it is a whole model's worth of device memory
+        params = shard_params_zero3(params, mesh)
+        # the moments are made in place on their shards: made on the
+        # default device, a model's f32 moments would all land on chip 0
+        shard = jax.tree.map(lambda x: x.sharding, params)
+        opt = jax.jit(adamw_init, out_shardings={
+            "m": shard, "v": shard,
+            "step": NamedSharding(mesh, P())})(params)
+    else:
+        opt = adamw_init(params)
+    state = {"params": params, "opt": opt}
+    del params, opt
+
+    b0 = jax.tree.map(jnp.asarray, data.batch_at(0))
+    if tc.engine == "manual":
         step_fn = make_manual_train_step(
             api, mesh, opt_cfg, sync=SyncConfig(
                 strategy=tc.sync,
                 backward_overlap=tc.backward_overlap))
+        # compile ahead of the first step (the jit call reuses this
+        # executable) so the device memory the step needs is on record
+        mem = step_fn.lower(state, b0).compile().memory_analysis()
+        if mem is not None:
+            gib = 1 << 30
+            on_log(f"compiled step per device: args "
+                   f"{mem.argument_size_in_bytes / gib:.2f} GiB, temps "
+                   f"{mem.temp_size_in_bytes / gib:.2f} GiB, outputs "
+                   f"{mem.output_size_in_bytes / gib:.2f} GiB, aliased "
+                   f"{mem.alias_size_in_bytes / gib:.2f} GiB")
     else:
         jitted, ss_fn, bs_fn = make_train_step(api, mesh, opt_cfg)
-        b0 = jax.tree.map(jnp.asarray, data.batch_at(0))
         step_fn = jitted(jax.eval_shape(lambda: state),
                          jax.eval_shape(lambda: b0))
 
@@ -491,17 +546,21 @@ def run_training(tc: TrainConfig, mesh: Mesh | None = None,
         "train_step_seconds", "wall time per training step",
         buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0))
 
+    step_seconds = []
+
     def one_step(state, step):
         import time as _time
         t0 = _time.perf_counter()
         with tracer.span("train/step", step=step):
             batch = jax.tree.map(jnp.asarray, data.batch_at(step))
             state, metrics = step_fn(state, batch)
-        step_hist.observe(_time.perf_counter() - t0)
+            loss = float(metrics["loss"])     # waits for the step
+        step_seconds.append(_time.perf_counter() - t0)
+        step_hist.observe(step_seconds[-1])
         if step % tc.log_every == 0:
-            on_log(f"step {step:5d}  loss {float(metrics['loss']):.4f}  "
+            on_log(f"step {step:5d}  loss {loss:.4f}  "
                    f"gnorm {float(metrics['gnorm']):.3f}")
-        losses.append(float(metrics["loss"]))
+        losses.append(loss)
         return state
 
     import contextlib
@@ -582,7 +641,9 @@ def run_training(tc: TrainConfig, mesh: Mesh | None = None,
         default_metrics().export(tc.metrics_path)
         on_log(f"metrics -> {tc.metrics_path}")
 
-    return {"state": state, "losses": losses}
+    # step_seconds[0] includes compiling the step for the auto engine; the
+    # manual engine compiled it before the first step
+    return {"state": state, "losses": losses, "step_seconds": step_seconds}
 
 
 def main():
@@ -602,9 +663,15 @@ def main():
     ap.add_argument("--faults", default=None, metavar="SPEC",
                     help="arm a deterministic chaos fault plan, e.g. "
                          "'seed=7,steps=200,payload_corrupt=0.05'")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="run the config at its published widths with "
+                         "this many layers (default: a smoke-shrunk "
+                         "config of the same family)")
     args = ap.parse_args()
+    enable_compile_cache()
     out = run_training(TrainConfig(
-        arch=args.arch, steps=args.steps, engine=args.engine,
+        arch=args.arch, layers=args.layers, steps=args.steps,
+        engine=args.engine,
         sync=args.sync, seq_len=args.seq_len, global_batch=args.batch,
         ckpt_dir=args.ckpt_dir, trace_path=args.trace,
         metrics_path=args.metrics, fault_plan=args.faults))

@@ -16,6 +16,8 @@ activation memory at T/C × state instead of T × state.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -62,9 +64,12 @@ def _token_shift(x: jax.Array, prev: jax.Array | None = None) -> jax.Array:
     return jnp.concatenate([prev, x[:, :-1]], axis=1)
 
 
-def _wkv_chunk(r, k, v, logw, u, s0, chunk: int):
+def _wkv_chunk(r, k, v, logw, u, s0, chunk: int, remat: bool = False):
     """Chunked WKV. r/k/v: (B,H,T,K|V); logw: (B,H,T,K) ≤ 0;
-    u: (H,K); s0: (B,H,K,V). Returns (out (B,H,T,V), s_final)."""
+    u: (H,K); s0: (B,H,K,V). Returns (out (B,H,T,V), s_final).
+    With `remat` the backward pass recomputes each chunk, so its
+    residuals are one state per chunk instead of the (C, C, K) pair
+    tensors."""
     B, H, T, K = k.shape
     V = v.shape[-1]
     C = min(chunk, T)
@@ -98,9 +103,34 @@ def _wkv_chunk(r, k, v, logw, u, s0, chunk: int):
         return a.reshape(B, H, nc, C, a.shape[-1]).transpose(2, 0, 1, 3, 4)
 
     s_fin, outs = lax.scan(
-        body, s0, (split(r), split(k), split(v), split(logw)))
+        jax.checkpoint(body) if remat else body, s0,
+        (split(r), split(k), split(v), split(logw)))
     out = outs.transpose(1, 2, 0, 3, 4).reshape(B, H, T, V)
     return out, s_fin
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def wkv_mosaic(r, k, v, logw, u, s0, chunk: int, interpret: bool = False):
+    """The Pallas WKV kernel (kernels/wkv.py) with a backward pass: the
+    kernel computes the forward; the gradient is XLA's derivative of the
+    same chunked recurrence (`_wkv_chunk`), as no backward kernel exists.
+    That derivative evaluates the recurrence again, chunk-rematerialized
+    so the (C, C, K) pair tensors of a whole sequence are never held."""
+    from repro.kernels.wkv import wkv
+    return wkv(r, k, v, logw, u, s0, chunk=chunk, interpret=interpret)
+
+
+def _wkv_mosaic_fwd(r, k, v, logw, u, s0, chunk, interpret):
+    return (wkv_mosaic(r, k, v, logw, u, s0, chunk, interpret),
+            (r, k, v, logw, u, s0))
+
+
+def _wkv_mosaic_bwd(chunk, interpret, res, g):
+    _, vjp = jax.vjp(lambda *a: _wkv_chunk(*a, chunk, remat=True), *res)
+    return vjp(g)
+
+
+wkv_mosaic.defvjp(_wkv_mosaic_fwd, _wkv_mosaic_bwd)
 
 
 def rwkv_time_mix(p: Params, x: jax.Array, cfg: ModelConfig, *,
@@ -131,10 +161,9 @@ def rwkv_time_mix(p: Params, x: jax.Array, cfg: ModelConfig, *,
         state = jnp.zeros((B, H, hd, hd), jnp.float32)
     if jax.default_backend() == "tpu":
         # Pallas kernel: state + pair tile stay in VMEM (kernels/wkv.py)
-        from repro.kernels.wkv import wkv as _wkv_kernel_call
-        out, s_fin = _wkv_kernel_call(
+        out, s_fin = wkv_mosaic(
             r.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), logw, p["u"], state, chunk=chunk)
+            v.astype(jnp.float32), logw, p["u"], state, chunk)
     else:
         out, s_fin = _wkv_chunk(r.astype(jnp.float32),
                                 k.astype(jnp.float32),

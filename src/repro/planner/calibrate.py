@@ -445,17 +445,18 @@ def measure_lax_cps(ns, sizes, axis_name: str = "cal", repeats: int = 3):
 
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
     from repro.core import collectives
-    from repro.core.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
     devs = jax.devices()
     out_ns, out_sizes, out_times = [], [], []
     for n in ns:
         if n > len(devs):
             continue
-        mesh = Mesh(np.array(devs[:n]), (axis_name,))
+        mesh = make_mesh((n,), (axis_name,), devices=devs[:n])
         for s in sizes:
             x = jnp.ones((n, int(s)), jnp.float32)
             fn = jax.jit(shard_map(

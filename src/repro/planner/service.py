@@ -1055,8 +1055,9 @@ class PlannerService:
         """GenModel-argmin gradient bucket size for a DP-axis list, with
         one lowered `CompiledSchedule` per axis (DESIGN.md §9).
 
-        Sweeps powers-of-two bucket sizes (plus the monolithic
-        single-bucket candidate) JOINTLY with the wire precision
+        Sweeps powers-of-two bucket sizes up to
+        `config.max_bucket_bytes` (plus the monolithic single-bucket
+        candidate when the whole set fits under it) JOINTLY with the wire precision
         (DESIGN.md §13): each (bucket, precision) candidate is priced per
         axis with the configured engine — per-bucket α, the γ/δ
         memory-access terms (including the quant/dequant passes), the
@@ -1229,7 +1230,10 @@ class PlannerService:
                 while nbytes < total * dsize and nbytes <= cfg.max_bucket_bytes:
                     cands.append(max(1, nbytes // dsize))
                     nbytes *= 2
-                cands.append(int(math.ceil(total)))    # monolithic: K = 1
+                # monolithic (K = 1) only under the ceiling: a bucket is
+                # materialized whole, so the ceiling bounds device memory
+                if total * dsize <= cfg.max_bucket_bytes or not cands:
+                    cands.append(int(math.ceil(total)))
 
             # the honest rank: the contended pipeline estimate (per-link
             # occupancy merge, DESIGN.md §15) replaces the optimistic

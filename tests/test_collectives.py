@@ -18,11 +18,12 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as C
 from repro.core.sync import SyncConfig, allreduce_int8_cps, sync_gradients
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 results = {}
 x = jnp.arange(8 * 40, dtype=jnp.float32).reshape(8, 40) / 7.0
 want = np.asarray(x.sum(0))
@@ -65,7 +66,7 @@ results["rs_value_contract"] = all(value_ok.values())
 # patch) — allreduce must match the sum on 3/5/6/7-device sub-meshes
 npo2 = {}
 for n in (3, 5, 6, 7):
-    sub = jax.sharding.Mesh(np.array(jax.devices()[:n]), ("x",))
+    sub = make_mesh((n,), ("x",), devices=jax.devices()[:n])
     y2 = jnp.arange(n * 19, dtype=jnp.float32).reshape(n, 19) / 3.0
     f = shard_map(lambda v: C.allreduce(v[0], "x", "rhd")[None],
                   mesh=sub, in_specs=P("x"), out_specs=P("x"))
@@ -106,7 +107,7 @@ results["sync_gentree"] = bool(
     and np.allclose(np.asarray(out["b"])[0], 16.0))
 
 # multi-axis (pod × data) hierarchical sync
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = make_mesh((2, 4), ("pod", "data"))
 z = jnp.arange(8 * 24, dtype=jnp.float32).reshape(2, 4, 24)
 def sync2(v):
     out = sync_gradients({"g": v[0, 0]}, [("data", 4), ("pod", 2)],
@@ -153,10 +154,11 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.sync import allreduce_topk
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 # sparse gradients: top-k with k covering all nonzeros must be EXACT
 g = jnp.zeros((8, 1000))
 g = g.at[:, :5].set(jax.random.normal(jax.random.PRNGKey(0), (8, 5)))

@@ -27,8 +27,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import plans, topology
 from repro.core.gentree import gentree
 from repro.core.lower import lower_plan
@@ -37,7 +38,7 @@ results = {}
 
 
 def run_sched(cs, n, size, dtype, seed=0):
-    mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
+    mesh = make_mesh((n,), ("x",), devices=jax.devices()[:n])
     x = jax.random.normal(jax.random.PRNGKey(seed), (n, size),
                           jnp.float32).astype(dtype)
     f = shard_map(lambda v: cs.allreduce(v[0], "x")[None],
@@ -86,7 +87,7 @@ for n in (3, 5, 6, 7):
     results[f"rhd_n{n}"] = run_sched(cs_rhd, n, 37, jnp.float32) < 1e-6
 
 # ---- RS/AG halves compose to the psum result ------------------------------
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = jax.random.normal(jax.random.PRNGKey(3), (8, 41))
 cs = lower_plan(gentree(topology.symmetric_tree(2, 4), 1e6).plan)
 g = shard_map(lambda v: cs.reduce_scatter(v[0], "x")[None],
@@ -121,7 +122,7 @@ results["allreduce_planned"] = bool(np.allclose(
     np.asarray(f(xa)), np.tile(np.asarray(xa.sum(0)), (8, 1)), rtol=1e-5))
 
 # ---- multi-axis (pod x data) strategy="plan" ------------------------------
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = make_mesh((2, 4), ("pod", "data"))
 z = jnp.arange(8 * 24, dtype=jnp.float32).reshape(2, 4, 24)
 f = shard_map(
     lambda v: {"g": sync_gradients({"g": v[0, 0]}, [("data", 4), ("pod", 2)],
@@ -137,11 +138,11 @@ from repro.launch.train import TrainConfig, run_training
 logs = []
 out_plan = run_training(TrainConfig(
     arch="stablelm-12b", steps=2, engine="manual", sync="plan",
-    seq_len=16, global_batch=8, log_every=10), smoke=True,
+    seq_len=16, global_batch=8, log_every=10),
     on_log=logs.append)
 out_psum = run_training(TrainConfig(
     arch="stablelm-12b", steps=2, engine="manual", sync="psum",
-    seq_len=16, global_batch=8, log_every=10), smoke=True,
+    seq_len=16, global_batch=8, log_every=10),
     on_log=logs.append)
 dl = max(abs(a - b) for a, b in zip(out_plan["losses"],
                                     out_psum["losses"]))

@@ -33,8 +33,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives, topology
 from repro.core import sync as sync_mod
 from repro.core.gentree import family_plan, gentree
@@ -46,7 +47,7 @@ results = {}
 
 
 def mesh_of(n):
-    return Mesh(np.array(jax.devices()[:n]), ("x",))
+    return make_mesh((n,), ("x",), devices=jax.devices()[:n])
 
 
 def rand(n, size, seed=0, dtype=jnp.float32):
@@ -201,13 +202,12 @@ layers._moe_ep = _counting_moe_ep
 try:
     res_plan = run_training(TrainConfig(arch="deepseek_moe_16b", steps=2,
                                         engine="manual", sync="plan",
-                                        seq_len=16, global_batch=8),
-                            smoke=True)
+                                        seq_len=16, global_batch=8))
 finally:
     layers._moe_ep = _orig_moe_ep
 res_psum = run_training(TrainConfig(arch="deepseek_moe_16b", steps=2,
                                     engine="manual", sync="psum",
-                                    seq_len=16, global_batch=8), smoke=True)
+                                    seq_len=16, global_batch=8))
 lp = [float(x) for x in res_plan["losses"]]
 ls = [float(x) for x in res_psum["losses"]]
 results["train_moe_plan_finite"] = bool(np.all(np.isfinite(lp)))

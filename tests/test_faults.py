@@ -628,12 +628,13 @@ import tempfile
 import jax
 import numpy as np
 from repro.launch.train import TrainConfig, run_training
+from repro.launch.mesh import make_mesh
 from repro.planner.service import default_service
 from repro.runtime.faults import FaultEvent, FaultInjector, FaultPlan
 from repro.runtime.metrics import default_metrics
 
 results = {}
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 kw = dict(arch="rwkv6-1.6b", steps=24, seq_len=32, global_batch=8,
           lr=1e-3, log_every=1000, engine="manual", sync="plan",
           ckpt_every=6, observe_sync=False)

@@ -271,3 +271,73 @@ def test_ssm_scan_matches_model_recurrence():
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(s_fin), np.asarray(s_model),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("T,chunk", [
+    (21, 8),      # odd length: chunk falls back to 7, three chunks
+    (13, 16),     # one chunk shorter than the requested chunk
+    (32, 32),     # exactly one chunk
+    (64, 32),     # a chunk boundary with state carried across it
+    (5, 1)])      # one-token chunks: the prefix sum is the token itself
+def test_wkv_prefix_sum_lengths_and_chunk_boundaries(T, chunk):
+    """The in-chunk Λ prefix sum (a product with the lower-triangular ones
+    matrix) vs the oracle's cumsum."""
+    from repro.kernels.wkv import wkv
+    B, H, K = 1, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(T * 31 + chunk), 6)
+    r = jax.random.normal(ks[0], (B, H, T, K))
+    k = jax.random.normal(ks[1], (B, H, T, K))
+    v = jax.random.normal(ks[2], (B, H, T, K))
+    lw = -jnp.exp(jax.random.normal(ks[3], (B, H, T, K)))
+    u = jax.random.normal(ks[4], (H, K)) * 0.1
+    s0 = jax.random.normal(ks[5], (B, H, K, K)) * 0.1
+    got, s_got = wkv(r, k, v, lw, u, s0, chunk=chunk, interpret=True)
+    want, s_want = ref.wkv_ref(r, k, v, lw, u, s0, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(s_got), np.asarray(s_want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_wkv_prefix_sum_zero_rows():
+    """Rows of zero decay (log w = 0: Λ stays flat) and zero keys."""
+    from repro.kernels.wkv import wkv
+    B, H, T, K = 1, 2, 24, 8
+    ks = jax.random.split(jax.random.PRNGKey(77), 5)
+    r = jax.random.normal(ks[0], (B, H, T, K))
+    k = jax.random.normal(ks[1], (B, H, T, K)).at[:, :, 5:9].set(0.0)
+    v = jax.random.normal(ks[2], (B, H, T, K))
+    lw = -jnp.exp(jax.random.normal(ks[3], (B, H, T, K)))
+    lw = lw.at[:, :, 8:16].set(0.0).at[:, 1].set(0.0)
+    u = jax.random.normal(ks[4], (H, K)) * 0.1
+    s0 = jnp.zeros((B, H, K, K))
+    got, s_got = wkv(r, k, v, lw, u, s0, chunk=8, interpret=True)
+    want, s_want = ref.wkv_ref(r, k, v, lw, u, s0, chunk=8)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(s_got), np.asarray(s_want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_wkv_mosaic_gradients_match_chunked_recurrence():
+    """The trainable kernel wrapper: Pallas forward, gradient from the
+    chunked recurrence — equal to differentiating the recurrence."""
+    from repro.models.recurrence import _wkv_chunk, wkv_mosaic
+    B, H, T, K = 1, 2, 24, 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    r, k, v = (jax.random.normal(x, (B, H, T, K)) for x in ks[:3])
+    lw = -jnp.exp(jax.random.normal(ks[3], (B, H, T, K)))
+    u = jax.random.normal(ks[4], (H, K)) * 0.1
+    s0 = jax.random.normal(ks[5], (B, H, K, K)) * 0.1
+
+    def loss(f):
+        def run(*a):
+            out, s = f(*a)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(s * s)
+        return jax.grad(run, argnums=tuple(range(6)))
+
+    got = loss(lambda *a: wkv_mosaic(*a, 8, True))(r, k, v, lw, u, s0)
+    want = loss(lambda *a: _wkv_chunk(*a, 8))(r, k, v, lw, u, s0)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)

@@ -307,8 +307,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import topology
 from repro.core.cost_model import PRECISIONS
 from repro.core.gentree import gentree
@@ -317,7 +318,7 @@ from repro.core.overlap import merge_schedules
 
 results = {}
 N, SIZE = 8, 173
-mesh = Mesh(np.array(jax.devices()[:N]), ("x",))
+mesh = make_mesh((N,), ("x",), devices=jax.devices()[:N])
 rng = np.random.default_rng(7)
 
 

@@ -195,3 +195,76 @@ def test_unknown_wire_rejected():
         wire_dtype("float16")
     assert set(WIRE_QMAX) == set(WIRES)
     assert QUANT_TILE == PRECISIONS["fp8"].scale_block
+
+
+# ---------------------------------------------------------------------------
+# scale blocks of SCALE_BLOCK tiles (the TPU-tiling-aligned layout): odd
+# lengths, rows longer than one block, all-zero rows
+# ---------------------------------------------------------------------------
+from repro.kernels.quant import SCALE_BLOCK  # noqa: E402
+
+# tile counts one below, at and past the one-block limit, and a
+# multi-block row whose last block is ragged; each L ends mid-tile
+BLOCK_NTS = [1, SCALE_BLOCK - 1, SCALE_BLOCK, SCALE_BLOCK + 1,
+             2 * SCALE_BLOCK + 44]
+
+
+def _rows(W, L, zero_row, seed):
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), (W, L),
+                                     jnp.float32)) * 3.0
+    if zero_row is not None:
+        x[zero_row] = 0.0
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("wire", WIRES)
+@pytest.mark.parametrize("nt", BLOCK_NTS)
+def test_quantize_scale_blocks_match_ref(wire, nt):
+    L = nt * QUANT_TILE - 5
+    x = _rows(3, L, zero_row=1, seed=nt)
+    q, s = quantize(x, wire, interpret=True)
+    qr, sr = ref.quantize_ref(x, wire)
+    assert q.shape == (3, nt * QUANT_TILE) and s.shape == (3, nt)
+    np.testing.assert_array_equal(np.asarray(q, np.float32),
+                                  np.asarray(qr, np.float32))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=2e-7)
+    assert not np.asarray(s)[1].any()          # zero row → zero scales
+    back = np.asarray(dequantize(q, s, out_len=L, interpret=True))
+    np.testing.assert_array_equal(
+        back, np.asarray(ref.dequantize_ref(q, s, out_len=L)))
+    assert not back[1].any()                   # … and decodes to exact 0
+
+
+@pytest.mark.parametrize("wire", WIRES)
+@pytest.mark.parametrize("nt", BLOCK_NTS)
+@pytest.mark.parametrize("own", [False, True])
+def test_quant_reduce_scale_blocks_match_ref(wire, nt, own):
+    L = nt * QUANT_TILE - 3
+    x = _rows(4, L, zero_row=2, seed=100 + nt)
+    q, s = ref.quantize_ref(x, wire)
+    o = (jax.random.normal(jax.random.PRNGKey(nt), (L,), jnp.float32)
+         if own else None)
+    got = np.asarray(quant_reduce(q, s, o, out_len=L, interpret=True))
+    want = np.asarray(ref.quant_reduce_ref(q, s, o, out_len=L))
+    assert got.shape == (L,)
+    # the same f32 products summed, possibly in another order (or fused
+    # multiply-adds): bounded by a few roundings of the absolute sum
+    terms = np.abs(np.asarray(ref.dequantize_ref(q, s, out_len=L))).sum(0)
+    if own:
+        terms = terms + np.abs(np.asarray(o))
+    assert np.all(np.abs(got - want) <= 4 * np.finfo(np.float32).eps
+                  * terms)
+
+
+@pytest.mark.parametrize("wire", WIRES)
+@pytest.mark.parametrize("nt", [SCALE_BLOCK - 1, 2 * SCALE_BLOCK + 44])
+def test_quant_reduce_requant_scale_blocks(wire, nt):
+    L = nt * QUANT_TILE
+    x = _rows(3, L, zero_row=0, seed=200 + nt)
+    q, s = quantize(x, wire, interpret=True)
+    qo, so = quant_reduce_requant(q, s, wire, interpret=True)
+    assert qo.shape == (L,) and so.shape == (nt,)
+    back = np.asarray(dequantize(qo[None], so[None], interpret=True))[0]
+    want = np.asarray(x).sum(0)
+    denom = float(np.max(np.abs(want)))
+    assert float(np.max(np.abs(back - want))) / denom < 2 * BUDGET[wire]

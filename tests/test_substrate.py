@@ -166,8 +166,9 @@ def test_straggler_watchdog():
 
 def test_elastic_remesh_identity():
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.runtime import elastic_remesh
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     state = {"w": jnp.ones((4, 4))}
     sh = {"w": NamedSharding(mesh, P())}
     out = elastic_remesh(state, sh)
@@ -182,6 +183,7 @@ def test_elastic_remesh_invalidates_bucket_schedules():
     CompiledSchedule and bucket plan derived from the planner cache must
     be dropped, and the next lookup must rebuild against the new size."""
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.planner.service import PlannerService
     from repro.runtime import elastic_remesh
 
@@ -190,7 +192,7 @@ def test_elastic_remesh_invalidates_bucket_schedules():
     assert bp8.axis_plans[0].schedule.n == 8
     assert svc.executable_count() > 0
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     state = {"w": jnp.ones((4, 4))}
     out = elastic_remesh(state, {"w": NamedSharding(mesh, P())},
                          planner=svc)

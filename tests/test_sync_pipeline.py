@@ -131,6 +131,15 @@ class TestGetBucketPlan:
         # never re-lowered per step
         assert b1.axis_plans[0].schedule is b2.axis_plans[0].schedule
 
+    def test_ceiling_bounds_every_candidate(self):
+        # a bucket is materialized whole on the device: above the
+        # ceiling not even the monolithic candidate is priced
+        cfg = BucketConfig(max_bucket_bytes=1 << 22)
+        bp = PlannerService().get_bucket_plan(self.AXES, 1e7, config=cfg)
+        assert len(bp.sweep) > 1
+        assert max(bp.sweep) * 4 <= cfg.max_bucket_bytes
+        assert bp.num_buckets > 1
+
     def test_pinned_bucket_bytes(self):
         svc = PlannerService()
         bp = svc.get_bucket_plan(self.AXES, 1e6,
@@ -216,7 +225,8 @@ import json, warnings
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as C
 from repro.core.bucketing import BucketConfig
 from repro.core.sync import SyncConfig, sync_gradients
@@ -227,7 +237,7 @@ TOL = {"float32": 1e-6, "bfloat16": 0.05}
 
 def sync_out(tree, axes, mesh_shape, cfg):
     '''The synced tree (and the psum reference) on the sharded mesh.'''
-    mesh = jax.make_mesh(mesh_shape, tuple(a for a, _ in reversed(axes)))
+    mesh = make_mesh(mesh_shape, tuple(a for a, _ in reversed(axes)))
     names = tuple(a for a, n in axes if n > 1)
     spec = P(*(a for a, _ in reversed(axes)))
     nlead = len(mesh_shape)
@@ -328,7 +338,7 @@ results["quant_f32_exact"] = bool(all(
     for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_f32))))
 
 # ---- allreduce_planned: chunked pipelined buckets + stats -----------------
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 xa = jnp.arange(8 * 133, dtype=jnp.float32).reshape(8, 133)
 stats = {}
 f = shard_map(lambda v: C.allreduce_planned(
@@ -339,6 +349,49 @@ out = np.asarray(jax.jit(f)(xa))
 results["planned_bucketed"] = bool(
     np.allclose(out, np.tile(np.asarray(xa.sum(0)), (8, 1)), rtol=1e-5)
     and stats["mode"] == "bucketed" and stats["num_buckets"] > 1)
+
+# ---- ZeRO-3 bucketed halves on the shard_params_zero3 row layout ----------
+from repro.core.bucketing import (zero3_gather_bucketed,
+                                  zero3_scatter_bucketed)
+from repro.core.sync import AxisPlan
+from repro.planner.service import PlannerService
+z3 = AxisPlan("x", "plan", schedule=PlannerService().get_axis_executable(
+    "x", 8, 4096.0).schedule)
+z3_shapes = [(3, 5), (7,), (0,), (2, 33), (1,), (64,)]
+z3_leaves = [jax.random.normal(jax.random.PRNGKey(30 + i), s)
+             for i, s in enumerate(z3_shapes)]
+z3_specs = [(x.shape, x.dtype) for x in z3_leaves]
+
+
+def z3_rows(x):
+    flat = x.reshape(-1)
+    flat = jnp.concatenate([flat, jnp.zeros(((-flat.size) % 8,),
+                                            flat.dtype)])
+    return flat.reshape(8, -1)
+
+
+for name, cap in (("small", 256), ("whole", 1 << 20)):
+    gat = shard_map(
+        lambda *s, cap=cap: [v[None] for v in zero3_gather_bucketed(
+            [x[0] for x in s], z3_specs, z3, cap, 8)],
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    got = jax.jit(gat)(*[z3_rows(x) for x in z3_leaves])
+    results[f"zero3_gather_{name}"] = all(
+        g.shape == (8,) + x.shape
+        and bool(np.array_equal(np.asarray(g),
+                                np.broadcast_to(np.asarray(x), g.shape)))
+        for g, x in zip(got, z3_leaves))
+    sca = shard_map(
+        lambda *f, cap=cap: [v[None] for v in zero3_scatter_bucketed(
+            [x[0] for x in f], z3, cap, 8)],
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    scale = jnp.arange(1.0, 9.0)
+    got = jax.jit(sca)(*[scale.reshape((8,) + (1,) * x.ndim) * x[None]
+                         for x in z3_leaves])
+    results[f"zero3_scatter_{name}"] = all(
+        bool(np.allclose(np.asarray(g), np.asarray(z3_rows(36.0 * x)),
+                         rtol=1e-6, atol=1e-6))
+        for g, x in zip(got, z3_leaves))
 
 # ---- allreduce_planned fallback: warn once + stats record -----------------
 from repro.planner.service import PlannerService
@@ -432,6 +485,8 @@ def results():
     "quant_fp8_pin", "quant_int8_pin", "quant_tol_sweep",
     "quant_int8_leaf", "quant_table6_fp8", "quant_f32_exact",
     "planned_bucketed",
+    "zero3_gather_small", "zero3_gather_whole",
+    "zero3_scatter_small", "zero3_scatter_whole",
     "fallback_correct", "fallback_stats", "fallback_warns_once"])
 def test_bucketed_sync(results, key):
     assert results[key] is True, (key, results)

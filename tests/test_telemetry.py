@@ -299,6 +299,7 @@ class TestWatchdogDatapath:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
 
         from repro.planner.service import PlannerService
         from repro.runtime import elastic_remesh
@@ -307,7 +308,7 @@ class TestWatchdogDatapath:
         svc = PlannerService(telemetry=tele)
         svc.get_bucket_plan([("data", 8)], 4096.0)
         tele.record_sample("root_sw", LevelSample(8, 1e3, 0.01, 0.01))
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         elastic_remesh({"w": jnp.ones((2,))},
                        {"w": NamedSharding(mesh, P())}, planner=svc)
         assert svc.executable_count() == 0

@@ -517,7 +517,8 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.sync import SyncConfig, sync_gradients
 from repro.runtime.trace import Tracer, set_default_tracer
 
@@ -526,7 +527,7 @@ CFG = SyncConfig(strategy="plan", bucket_bytes=4096)
 
 
 def run_once():
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = make_mesh((8,), ("x",))
     key = jax.random.PRNGKey(7)
     grads = {}
     for i, size in enumerate((1024, 517, 33)):

@@ -43,9 +43,10 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from repro.launch.train import TrainConfig, run_training
+from repro.launch.mesh import make_mesh
 
 results = {}
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 kw = dict(arch="rwkv6-1.6b", steps=8, seq_len=32, global_batch=8,
           lr=1e-3, log_every=1000)
 auto = run_training(TrainConfig(**kw, engine="auto"), mesh=mesh)
@@ -61,7 +62,7 @@ results["auto_final"] = auto["losses"][-1]
 results["auto_decreased"] = bool(auto["losses"][-1] < auto["losses"][0])
 
 # TP mesh: auto engine with model axis > 1
-mesh_tp = jax.make_mesh((2, 4), ("data", "model"))
+mesh_tp = make_mesh((2, 4), ("data", "model"))
 tp = run_training(TrainConfig(arch="stablelm-12b", steps=6, seq_len=32,
                               global_batch=4, lr=1e-3, log_every=1000),
                   mesh=mesh_tp)

@@ -1,0 +1,94 @@
+"""The comparison that decides `correct`, at a size a test run holds, on
+the CPU: the harness's look for a chip is skipped and the rest of a run is
+driven with the timed path broken underneath; each fault that a cell can
+have, and the control (the reference in float8 put in the program's
+place), must come out not correct under the cell's own limits."""
+from __future__ import annotations
+
+import copy
+import json
+import sys
+import time
+from pathlib import Path
+
+import pytest
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(ROOT / "src"))
+
+import harness  # noqa: E402
+
+TINY = {
+    "dense": dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+                  head_dim=16, intermediate_size=128, vocab_size=512,
+                  num_hidden_layers=2),
+}
+CELLS = [w["name"] for w in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["workloads"] if w["chips"] == 1]
+
+
+def tiny(name: str) -> dict:
+    c = copy.deepcopy(harness.cell(name))
+    c["config"].update(TINY[c["config"]["family"]])
+    c["workload"]["traffic"]["seq_len"] = 64
+    return c
+
+
+def drive(c, fault=None):
+    import jax
+    drv = harness.load_module(c["driver"])
+    return drv.run(c, 2**31 + 12345, 0.3, False, t_start=time.perf_counter(),
+                   devices=jax.devices()[:c["entry"]["chips"]], fault=fault)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_reads_its_numbers(cell):
+    out = drive(tiny(cell))
+    assert out["attempted"] >= 1 and out["failed"] == 0
+    assert out["checks"]["input_mismatch"]["value"] == 0
+    assert set(out["checks"]) == set(harness.cell(cell)["workload"]["limits"])
+    for ch in out["checks"].values():
+        assert ch["value"] is not None and ch["value"] >= 0
+
+
+@pytest.mark.parametrize("fault", ["frozen", "half_batch"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_broken_step_is_not_correct(cell, fault):
+    out = drive(tiny(cell), fault)
+    assert out["correct"] is False, out["checks"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_is_not_correct(cell):
+    """The reference with float8 products against the reference."""
+    import oracle
+    c = tiny(cell)
+    drv = harness.load_module(c["driver"])
+    import jax
+    tn = drv.Trainer(c, jax.devices()[:1])
+    tn.start(2**31 + 99)
+    want = tn.reference()
+    got = tn.reference(mm=oracle.FP8)
+    ok, checks = harness.judge(oracle.gaps(got, want),
+                               {k: v for k, v in c["workload"]["limits"].items()
+                                if k != "input_mismatch"})
+    assert ok is False, checks
+
+
+def test_rwkv6_reference_follows_the_program():
+    """The rwkv6-1.6b reference (staged for its four-chip cell) takes the
+    program's one-device steps at a tiny size: the same losses to bf16
+    rounding, and gradients of the same norms."""
+    c = tiny(CELLS[0])
+    c["config"] = json.loads(
+        (BENCH / "configs" / "rwkv6-1.6b.json").read_text())
+    c["config"].update(hidden_size=64, num_attention_heads=4, head_size=16,
+                       intermediate_size=128, vocab_size=512,
+                       num_hidden_layers=2)
+    c["reference"] = BENCH / "configs" / "rwkv6-1.6b.ref.py"
+    c["workload"]["traffic"]["seq_len"] = 128
+    out = drive(c)
+    assert out["checks"]["loss_gap"]["value"] < 5e-3
+    assert out["checks"]["grad_gap"]["value"] < 0.1

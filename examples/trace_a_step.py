@@ -7,13 +7,14 @@ AllGather overlapping bucket k+1's ReduceScatter — on an 8-host-device
 mesh, and exports a Chrome-trace JSON you can load in chrome://tracing
 or https://ui.perfetto.dev.
 
-The spans inside the shard_map body (`sync/bucketed`, per-bucket
-`bucket/rs` / `bucket/ag`, per-round `exec/...`) fire at *trace time* —
-they record the staging-out of the schedule, nested exactly as the
-schedule executes, not device wall-clock (DESIGN.md §11). The planner
-spans (`planner/generate_plan`, `planner/bucket_sweep`) and the metrics
-(cache hits/misses, bucket counts, pipeline occupancy) are host-side and
-real either way.
+The names inside the shard_map body (`sync/bucketed`, per-bucket
+`bucket/rs<i>` / `bucket/ag<i>`, per-step and per-round `exec/...`) are
+scopes: they name the device ops of the compiled step (its `op_name`
+metadata, which a profiler trace carries), nested exactly as the schedule
+executes, and cost nothing when it runs. The host spans
+(`example/sync_step`, `planner/generate_plan`, `planner/bucket_sweep`)
+go to the ring and the Chrome trace, and appear on the JAX profiler's
+clock whenever a profiler session records (DESIGN.md §11).
 
 Run:  PYTHONPATH=src python examples/trace_a_step.py
 """
@@ -22,6 +23,7 @@ os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,17 +82,22 @@ def main():
     tracer.export_chrome(TRACE_PATH)
     default_metrics().export(METRICS_PATH)
 
-    # prove the artifact is loadable and the spans nest as the schedule
-    # executes: sync -> bucket halves -> rounds
+    # prove the artifact is loadable and holds the host span; the step's
+    # scopes nest as the schedule executes: sync -> bucket halves -> rounds
     with open(TRACE_PATH) as fh:
         doc = json.load(fh)
     events = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
     names = {e["name"] for e in events}
-    for expected in ("example/sync_step", "sync/bucketed", "exec/round"):
-        assert expected in names, f"missing span {expected!r}"
-    print(f"wrote {TRACE_PATH}: {len(events)} spans "
-          f"({len(names)} distinct), e.g. "
-          + ", ".join(sorted(names)[:6]))
+    assert "example/sync_step" in names, "missing span 'example/sync_step'"
+    lowered = jax.jit(f).lower(grads).as_text(debug_info=True)
+    scopes = sorted(set(re.findall(
+        r"(?:sync|bucket|exec)/[a-z_]+\d*(?:/step\d+)?", lowered)))
+    for expected in ("sync/bucketed", "bucket/rs_ag0", "exec/round0"):
+        assert expected in scopes, f"missing scope {expected!r}"
+    print(f"wrote {TRACE_PATH}: {len(events)} host spans "
+          f"({len(names)} distinct), e.g. " + ", ".join(sorted(names)[:6]))
+    print(f"device scopes of the step ({len(scopes)}), e.g. "
+          + ", ".join(scopes[:8]))
     print(f"wrote {METRICS_PATH} (+ .prom): "
           f"{len(default_metrics().snapshot())} metrics")
     print("load the trace in chrome://tracing or ui.perfetto.dev")

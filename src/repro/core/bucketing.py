@@ -43,7 +43,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Sequence
 
 from repro.runtime.metrics import default_metrics
-from repro.runtime.trace import default_tracer
+from repro.runtime.trace import scope
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +288,6 @@ def execute_buckets(leaves, buckets: Sequence[Bucket], axis_plans, *,
 
     k = len(flats)
     order = list(range(k - 1, -1, -1)) if reverse else list(range(k))
-    tracer = default_tracer()
     results: list = [None] * k
     use_merged = (merged is not None and pipeline and k > 1
                   and len(axis_plans) == 1 and supports_halves(axis_plans))
@@ -298,52 +297,48 @@ def execute_buckets(leaves, buckets: Sequence[Bucket], axis_plans, *,
         prev = None
         for i in order:
             if prev is None:
-                with tracer.span("bucket/rs", bucket=i,
-                                 elements=int(flats[i].size)):
+                with scope(f"bucket/rs{i}"):
                     shards[i] = pl.schedule.reduce_scatter(
                         flats[i], pl.axis, fused_reduce=fused_reduce)
             else:
-                with tracer.span("bucket/rs_ag", bucket=i, drains=prev):
+                with scope(f"bucket/rs_ag{i}"):
                     shards[i], full = merged.rs_ag(
                         flats[i], shards[prev], pl.axis,
                         fused_reduce=fused_reduce)
                 results[prev] = full[:int(flats[prev].size)]
                 shards[prev] = None
             prev = i
-        with tracer.span("bucket/ag", bucket=prev):
+        with scope(f"bucket/ag{prev}"):
             results[prev] = pl.schedule.all_gather(
                 shards[prev], pl.axis)[:int(flats[prev].size)]
     elif pipeline and k > 1 and supports_halves(axis_plans):
         shards, sizes = [None] * k, [None] * k
         prev = None
         for i in order:
-            with tracer.span("bucket/rs", bucket=i,
-                             elements=int(flats[i].size)):
+            with scope(f"bucket/rs{i}"):
                 shards[i], sizes[i] = _rs_chain(flats[i], axis_plans,
                                                 fused_reduce)
             if prev is not None:
-                with tracer.span("bucket/ag", bucket=prev):
+                with scope(f"bucket/ag{prev}"):
                     results[prev] = _ag_chain(shards[prev], axis_plans,
                                               sizes[prev])
                 shards[prev] = None
             prev = i
-        with tracer.span("bucket/ag", bucket=prev):
+        with scope(f"bucket/ag{prev}"):
             results[prev] = _ag_chain(shards[prev], axis_plans,
                                       sizes[prev])
     elif supports_halves(axis_plans):
         for i in order:
-            with tracer.span("bucket/rs", bucket=i,
-                             elements=int(flats[i].size)):
+            with scope(f"bucket/rs{i}"):
                 shard, sizes = _rs_chain(flats[i], axis_plans,
                                          fused_reduce)
-            with tracer.span("bucket/ag", bucket=i):
+            with scope(f"bucket/ag{i}"):
                 results[i] = _ag_chain(shard, axis_plans, sizes)
     else:
         # no canonical shard layout on some axis: sequential whole-plan
         # AllReduce per bucket (still amortizes per-leaf launches)
         for i in order:
-            with tracer.span("bucket/allreduce", bucket=i,
-                             elements=int(flats[i].size)):
+            with scope(f"bucket/allreduce{i}"):
                 results[i] = _allreduce_chain(flats[i], axis_plans,
                                               fused_reduce)
 
@@ -453,12 +448,7 @@ def sync_bucketed(grads, axes: Sequence[tuple[str, int]], cfg, *,
                                                     telemetry=tele))
             if pl.schedule is not None else pl
             for pl in axis_plans]
-    with default_tracer().span("sync/bucketed", buckets=len(buckets),
-                               bucket_bytes=bplan.bucket_bytes,
-                               source=bplan.source,
-                               overlap=bplan.overlap.get("mode",
-                                                         "sequential"),
-                               reverse=reverse):
+    with scope("sync/bucketed"):
         out = execute_buckets(leaves, buckets, axis_plans,
                               pipeline=bcfg.pipeline,
                               fused_reduce=fused_reduce,
@@ -506,14 +496,12 @@ def zero3_gather_bucketed(shards, specs, plan, bucket_bytes: int, n: int
                         max(1, int(bucket_bytes) // max(1, int(n))),
                         itemsizes=[s.dtype.itemsize for s in shards])
     out = [None] * len(shards)
-    tracer = default_tracer()
     for bi, bk in enumerate(buckets):
         row = jnp.concatenate([shards[i].reshape(-1) for i in bk.indices]) \
             if len(bk.indices) > 1 else shards[bk.indices[0]].reshape(-1)
         row = _pad_to(row, k)
         width = row.size
-        with tracer.span("bucket/zero3_ag", bucket=bi,
-                         leaves=len(bk.indices)):
+        with scope(f"bucket/zero3_ag{bi}"):
             flat = cs.all_gather(row, plan.axis)
         off = 0
         for i, c in zip(bk.indices, bk.sizes):
@@ -554,7 +542,6 @@ def zero3_scatter_bucketed(fulls, plan, bucket_bytes: int, n: int,
     buckets = partition(sizes, [x.dtype for x in fulls], bucket_bytes,
                         itemsizes=[x.dtype.itemsize for x in fulls])
     out = [None] * len(fulls)
-    tracer = default_tracer()
     issue = list(enumerate(buckets))
     if reverse:
         issue.reverse()
@@ -568,8 +555,7 @@ def zero3_scatter_bucketed(fulls, plan, bucket_bytes: int, n: int,
                 pieces.append(v[j * chunks[i]:(j + 1) * chunks[i]])
             if pad:
                 pieces.append(jnp.zeros((pad,), vecs[0].dtype))
-        with tracer.span("bucket/zero3_rs", bucket=bi,
-                         leaves=len(bk.indices)):
+        with scope(f"bucket/zero3_rs{bi}"):
             shard = cs.reduce_scatter(jnp.concatenate(pieces), plan.axis)
         off = 0
         for i in bk.indices:

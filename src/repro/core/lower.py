@@ -48,7 +48,7 @@ from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
-from repro.runtime.trace import default_tracer
+from repro.runtime.trace import default_tracer, scope
 
 from .plans import Plan
 
@@ -103,9 +103,7 @@ def _round_jax(rd: PermRound, buf, stage, idx, zero, axis_name: str,
     import jax.numpy as jnp
     from jax import lax
 
-    with default_tracer().span("exec/round", round=ri,
-                               width=int(rd.send_blks.shape[1]),
-                               pairs=len(rd.perm)):
+    with scope(f"exec/round{ri}"):
         w = rd.send_blks.shape[1]
         chunk = buf.shape[1]
         sb = jnp.asarray(rd.send_blks)[idx]      # (W,)
@@ -131,8 +129,7 @@ def _fold_jax(fd: FoldPhase, buf, stage, idx, zero,
     import jax.numpy as jnp
     from jax import lax
 
-    with default_tracer().span("exec/fold", fold=fi,
-                               fan=int(fd.ops.shape[1])):
+    with scope(f"exec/fold{fi}"):
         blk = jnp.asarray(fd.blk)[idx]
         safeb = jnp.maximum(blk, 0)
         own = lax.dynamic_index_in_dim(buf, safeb, 0, keepdims=False)
@@ -224,28 +221,22 @@ class CompiledSchedule:
     # ---- jax execution (call inside shard_map) -----------------------------
     def _run_steps(self, steps: Sequence[ExecStep], buf, axis_name: str,
                    fused_reduce: Callable | None, phase: str = "steps"):
-        # Span caveat (DESIGN.md §11): this body runs at shard_map/jit
-        # TRACE time, so span durations measure staging-out, not device
-        # execution — but the span *structure* (which step, which round,
-        # which fold, at what width/fan) is exactly the executed
-        # schedule. Device wall time stays the telemetry hub's job; the
-        # numpy mirror below records real durations for the same spans.
+        # This body runs at shard_map/jit trace time: the scopes name the
+        # device ops of each step, round and fold (DESIGN.md §11); the
+        # numpy mirror below times the same structure on the host.
         import jax.numpy as jnp
         from jax import lax
 
         if self.wire is not None:
             return self._run_steps_wire(steps, buf, axis_name, phase)
 
-        tracer = default_tracer()
         idx = lax.axis_index(axis_name)
         chunk = buf.shape[1]
         zero = jnp.zeros((chunk,), buf.dtype)
         for si, st in enumerate(steps):
             if not st.rounds and not st.folds:
                 continue
-            with tracer.span(f"exec/{phase}/step", step=si,
-                             rounds=len(st.rounds), folds=len(st.folds),
-                             plan=self.plan_name):
+            with scope(f"exec/{phase}/step{si}"):
                 stage = jnp.zeros((max(st.n_slots, 1), chunk), buf.dtype)
                 for ri, rd in enumerate(st.rounds):
                     stage = _round_jax(rd, buf, stage, idx, zero,
@@ -271,7 +262,6 @@ class CompiledSchedule:
 
         from repro.kernels import ops as kops
 
-        tracer = default_tracer()
         idx = lax.axis_index(axis_name)
         chunk = buf.shape[1]
         wire = self.wire
@@ -287,17 +277,13 @@ class CompiledSchedule:
         for si, st in enumerate(steps):
             if not st.rounds and not st.folds:
                 continue
-            with tracer.span(f"exec/{phase}/step", step=si,
-                             rounds=len(st.rounds), folds=len(st.folds),
-                             plan=self.plan_name, wire=wire.name):
+            with scope(f"exec/{phase}/step{si}"):
                 slots = max(st.n_slots, 1)
                 stage_q = jnp.zeros((slots, lanes), wdtype)
                 stage_s = (jnp.zeros((slots, nt), jnp.float32)
                            if scaled else None)
                 for ri, rd in enumerate(st.rounds):
-                    with tracer.span("exec/round", round=ri,
-                                     width=int(rd.send_blks.shape[1]),
-                                     pairs=len(rd.perm), wire=wire.name):
+                    with scope(f"exec/round{ri}"):
                         w = rd.send_blks.shape[1]
                         sb = jnp.asarray(rd.send_blks)[idx]      # (W,)
                         rows = [jnp.where(
@@ -330,9 +316,7 @@ class CompiledSchedule:
                                 stage_s, jnp.where(off >= 0, s, cur_s),
                                 (safe, 0))
                 for fi, fd in enumerate(st.folds):
-                    with tracer.span("exec/fold", fold=fi,
-                                     fan=int(fd.ops.shape[1]),
-                                     wire=wire.name):
+                    with scope(f"exec/fold{fi}"):
                         blk = jnp.asarray(fd.blk)[idx]
                         safeb = jnp.maximum(blk, 0)
                         own = lax.dynamic_index_in_dim(buf, safeb, 0,
@@ -392,8 +376,7 @@ class CompiledSchedule:
         if pad:
             flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
         buf = flat.reshape(self.num_blocks, -1)
-        with default_tracer().span("exec/allreduce", plan=self.plan_name,
-                                   n=self.n, blocks=self.num_blocks):
+        with scope("exec/allreduce"):
             buf = self._run_steps(self.rs, buf, axis_name, fused_reduce,
                                   phase="rs")
             buf = self._run_steps(self.ag, buf, axis_name, fused_reduce,
@@ -420,8 +403,7 @@ class CompiledSchedule:
         if pad:
             flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
         buf = flat.reshape(self.num_blocks, -1)
-        with default_tracer().span("exec/reduce_scatter",
-                                   plan=self.plan_name, n=self.n):
+        with scope("exec/reduce_scatter"):
             buf = self._run_steps(self.rs, buf, axis_name, fused_reduce,
                                   phase="rs")
             if self.reorder is not None:
@@ -447,8 +429,7 @@ class CompiledSchedule:
         idx = lax.axis_index(axis_name)
         buf = lax.dynamic_update_slice_in_dim(
             buf, flat.reshape(k, -1), idx * k, axis=0)
-        with default_tracer().span("exec/all_gather",
-                                   plan=self.plan_name, n=self.n):
+        with scope("exec/all_gather"):
             if self.unorder is not None:
                 buf = self._run_steps([self.unorder], buf, axis_name,
                                       None, phase="unorder")
@@ -473,8 +454,7 @@ class CompiledSchedule:
         self._check_axis(axis_name)
         shape = x.shape
         buf = x.reshape(self.num_blocks, -1)
-        with default_tracer().span("exec/all_to_all", plan=self.plan_name,
-                                   n=self.n, blocks=self.num_blocks):
+        with scope("exec/all_to_all"):
             buf = self._run_steps(self.ag, buf, axis_name, None,
                                   phase="a2a")
         return buf.reshape(shape)
@@ -487,8 +467,7 @@ class CompiledSchedule:
         self._check_axis(axis_name)
         shape = x.shape
         buf = x.reshape(1, -1)
-        with default_tracer().span("exec/p2p", plan=self.plan_name,
-                                   n=self.n):
+        with scope("exec/p2p"):
             buf = self._run_steps(self.ag, buf, axis_name, None,
                                   phase="p2p")
         return buf.reshape(shape)
@@ -497,8 +476,9 @@ class CompiledSchedule:
     def _run_steps_numpy(self, steps: Sequence[ExecStep],
                          buf: np.ndarray,
                          phase: str = "steps") -> np.ndarray:
-        # Same span names as the jax path, but here durations are real —
-        # this is the interpreter the equivalence suite runs.
+        # Host spans of the structure the jax path names with scopes; here
+        # durations are real — this is the interpreter the equivalence
+        # suite runs.
         n = self.n
         tracer = default_tracer()
         for si, st in enumerate(steps):

@@ -44,7 +44,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.runtime.metrics import default_metrics
-from repro.runtime.trace import default_tracer
+from repro.runtime.trace import default_tracer, scope
 
 from .lower import (ExecStep, LoweringError, PermRound, _fold_jax,
                     _round_jax, _GUARD_REGISTRY, guard_schedule)
@@ -248,11 +248,7 @@ class MergedSchedule:
         # semantics: fused_reduce never applies there in the sequential
         # entry points, so mirror that boundary exactly
         n_rs = len(a.rs)
-        info = self.info
-        with default_tracer().span(
-                "overlap/rs_ag", plan=self.plan_name, n=self.n,
-                round_pairs=info.round_pairs, coalesced=info.coalesced,
-                serialized=info.serialized):
+        with scope("overlap/rs_ag"):
             if a.wire is None and b.wire is None:
                 chunk_a, chunk_b = buf_a.shape[1], buf_b.shape[1]
                 zero_a = jnp.zeros((chunk_a,), buf_a.dtype)
@@ -260,10 +256,7 @@ class MergedSchedule:
                 for i in range(max(len(steps_a), len(steps_b))):
                     sa = steps_a[i] if i < len(steps_a) else None
                     sb = steps_b[i] if i < len(steps_b) else None
-                    with default_tracer().span(
-                            "overlap/step", step=i,
-                            rs_rounds=len(sa.rounds) if sa else 0,
-                            ag_rounds=len(sb.rounds) if sb else 0):
+                    with scope(f"overlap/step{i}"):
                         stage_a = jnp.zeros(
                             (max(sa.n_slots, 1), chunk_a),
                             buf_a.dtype) if sa is not None else None

@@ -370,7 +370,6 @@ def sync_gradients(grads, axes: Sequence[tuple[str, int]], cfg: SyncConfig,
                                           schedule=pl.schedule)
         return g
 
-    from repro.runtime.trace import default_tracer
-    with default_tracer().span("sync/gradients", strategy=cfg.strategy,
-                               axes=len(plans)):
+    from repro.runtime.trace import scope
+    with scope("sync/gradients"):
         return jax.tree.map(leaf, grads)

@@ -18,6 +18,8 @@ from typing import Iterator
 
 import numpy as np
 
+from repro.runtime.trace import default_tracer
+
 
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
@@ -41,32 +43,33 @@ class SyntheticLM:
         self._probs = probs / probs.sum()
 
     def batch_at(self, step: int) -> dict:
-        cfg = self.cfg
-        rng = np.random.default_rng(
-            np.random.SeedSequence([cfg.seed, step]))
-        B, T = cfg.global_batch, cfg.seq_len
-        toks = rng.choice(cfg.vocab, size=(B, T + 1), p=self._probs)
-        # markov structure: with prob markov_mix, next = (cur*7+3) % V —
-        # applied sequentially so the chain composes (label_t really is
-        # f(final token_t) wherever the coin lands heads)
-        take = rng.random((B, T)) < cfg.markov_mix
-        for t in range(T):
-            follow = (toks[:, t] * 7 + 3) % cfg.vocab
-            toks[:, t + 1] = np.where(take[:, t], follow, toks[:, t + 1])
-        out = {
-            "tokens": toks[:, :-1].astype(np.int32),
-            "labels": toks[:, 1:].astype(np.int32),
-        }
-        if cfg.embed_dim:        # vlm stub: embeddings instead of tokens
-            out["embeds"] = rng.standard_normal(
-                (B, T, cfg.embed_dim)).astype(np.float32) * 0.02
-            out["mrope_positions"] = np.broadcast_to(
-                np.arange(T, dtype=np.int32), (3, B, T)).copy()
-            del out["tokens"]
-        if cfg.frames:           # audio stub: frame embeddings
-            out["frames"] = rng.standard_normal(
-                (B, cfg.frames, cfg.embed_dim)).astype(np.float32) * 0.02
-        return out
+        with default_tracer().span("data/batch"):
+            cfg = self.cfg
+            rng = np.random.default_rng(
+                np.random.SeedSequence([cfg.seed, step]))
+            B, T = cfg.global_batch, cfg.seq_len
+            toks = rng.choice(cfg.vocab, size=(B, T + 1), p=self._probs)
+            # markov structure: with prob markov_mix, next = (cur*7+3) % V —
+            # applied sequentially so the chain composes (label_t really is
+            # f(final token_t) wherever the coin lands heads)
+            take = rng.random((B, T)) < cfg.markov_mix
+            for t in range(T):
+                follow = (toks[:, t] * 7 + 3) % cfg.vocab
+                toks[:, t + 1] = np.where(take[:, t], follow, toks[:, t + 1])
+            out = {
+                "tokens": toks[:, :-1].astype(np.int32),
+                "labels": toks[:, 1:].astype(np.int32),
+            }
+            if cfg.embed_dim:        # vlm stub: embeddings instead of tokens
+                out["embeds"] = rng.standard_normal(
+                    (B, T, cfg.embed_dim)).astype(np.float32) * 0.02
+                out["mrope_positions"] = np.broadcast_to(
+                    np.arange(T, dtype=np.int32), (3, B, T)).copy()
+                del out["tokens"]
+            if cfg.frames:           # audio stub: frame embeddings
+                out["frames"] = rng.standard_normal(
+                    (B, cfg.frames, cfg.embed_dim)).astype(np.float32) * 0.02
+            return out
 
 
 def make_pipeline(cfg: DataConfig, start_step: int = 0,

@@ -62,6 +62,7 @@ def fused_reduce(parts: jax.Array, *, tile_l: int = DEFAULT_TILE_L,
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((parts.shape[1],), parts.dtype),
         interpret=interpret,
+        name="fused_reduce",
     )(parts)
     return out[:L] if out.shape[0] != L else out
 
@@ -99,5 +100,6 @@ def grouped_reduce(parts: jax.Array, fan_in: int, *,
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((parts.shape[1],), parts.dtype),
         interpret=interpret,
+        name="grouped_reduce",
     )(parts)
     return out[:L] if out.shape[0] != L else out

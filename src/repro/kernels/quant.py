@@ -117,6 +117,7 @@ def quantize(x: jax.Array, wire: str = "float8_e4m3fn", *,
         out_shape=[jax.ShapeDtypeStruct((W, nt * tile), wire_dtype(wire)),
                    jax.ShapeDtypeStruct((W, nt), jnp.float32)],
         interpret=interpret,
+        name="quantize",
     )(x)
 
 
@@ -140,6 +141,7 @@ def dequantize(q: jax.Array, scales: jax.Array, *,
         out_specs=pl.BlockSpec((W, tb * tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((W, Lp), jnp.float32),
         interpret=interpret,
+        name="dequantize",
     )(q, scales)
     return out if out_len is None or out_len == Lp else out[:, :out_len]
 
@@ -188,6 +190,7 @@ def quant_reduce(q: jax.Array, scales: jax.Array,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Lp,), jnp.float32),
         interpret=interpret,
+        name="quant_reduce",
     )(*args)
     n = Lp if out_len is None else out_len
     return out if n == out.shape[0] else out[:n]
@@ -223,5 +226,6 @@ def quant_reduce_requant(q: jax.Array, scales: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((nt, tile), wire_dtype(wire)),
                    jax.ShapeDtypeStruct((1, nt), jnp.float32)],
         interpret=interpret,
+        name="quant_reduce_requant",
     )(q, scales)
     return qo.reshape(-1), so.reshape(-1)

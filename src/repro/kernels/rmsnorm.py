@@ -37,6 +37,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w)
     if pad:
         out = out[:rows]

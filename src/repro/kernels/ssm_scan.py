@@ -89,5 +89,6 @@ def ssm_scan(u: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
+        name="ssm_scan",
     )(u, dt, b, c, log_a, s0)
     return y, s_fin

@@ -117,5 +117,6 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
+        name="wkv",
     )(rf, kf, vf, lwf, uf, s0f)
     return out.reshape(B, H, T, V), s_fin.reshape(B, H, K, V)

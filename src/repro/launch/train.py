@@ -34,6 +34,7 @@ from repro.core import collectives
 from repro.core.sync import AxisPlan, SyncConfig, plan_axes_gentree
 from repro.models.registry import ModelAPI
 from repro.optim import AdamWConfig, adamw_init, adamw_update
+from repro.runtime.trace import scope
 from . import sharding as shr
 from .compile_cache import enable_compile_cache
 from .mesh import axis_sizes, dp_axes, make_mesh
@@ -305,14 +306,15 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
                 "ZeRO-3 steps traced on this sync path").inc()
 
             flat_shards = jax.tree.leaves(p_shards)
-            if bplan is not None:
-                gathered = bucketing.zero3_gather_bucketed(
-                    [s[0] for s in flat_shards], flat_sd,
-                    bplan.axis_plans[0], bplan.bucket_bytes, ndp)
-            else:
-                gathered = [
-                    _gather_leaf(s[0], sd[0], sd[1], plans)
-                    for s, sd in zip(flat_shards, flat_sd)]
+            with scope("zero3/gather"):
+                if bplan is not None:
+                    gathered = bucketing.zero3_gather_bucketed(
+                        [s[0] for s in flat_shards], flat_sd,
+                        bplan.axis_plans[0], bplan.bucket_bytes, ndp)
+                else:
+                    gathered = [
+                        _gather_leaf(s[0], sd[0], sd[1], plans)
+                        for s, sd in zip(flat_shards, flat_sd)]
             params = jax.tree.unflatten(jax.tree.structure(p_shards),
                                         gathered)
             if use_ep:
@@ -326,17 +328,19 @@ def make_manual_train_step(api: ModelAPI, mesh: Mesh,
                     lambda p: api.loss_fn(p, batch_local,
                                           remat=True))(params)
             # mean over DP shards happens inside the reduce; rescale
-            if bplan is not None:
-                rows = bucketing.zero3_scatter_bucketed(
-                    jax.tree.leaves(grads), bplan.axis_plans[0],
-                    bplan.bucket_bytes, ndp,
-                    reverse=getattr(sync, "backward_overlap", False))
-                g_shards = jax.tree.unflatten(
-                    jax.tree.structure(grads),
-                    [(r / ndp)[None] for r in rows])
-            else:
-                g_shards = jax.tree.map(
-                    lambda g: (_scatter_leaf(g, plans) / ndp)[None], grads)
+            with scope("zero3/scatter"):
+                if bplan is not None:
+                    rows = bucketing.zero3_scatter_bucketed(
+                        jax.tree.leaves(grads), bplan.axis_plans[0],
+                        bplan.bucket_bytes, ndp,
+                        reverse=getattr(sync, "backward_overlap", False))
+                    g_shards = jax.tree.unflatten(
+                        jax.tree.structure(grads),
+                        [(r / ndp)[None] for r in rows])
+                else:
+                    g_shards = jax.tree.map(
+                        lambda g: (_scatter_leaf(g, plans) / ndp)[None],
+                        grads)
             loss = jax.lax.pmean(loss, tuple(a for a, _ in axes))
             new_p, new_o, gn = adamw_update(p_shards, g_shards, opt, opt_cfg)
             gn = jax.lax.pmean(gn, tuple(a for a, _ in axes))

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.runtime.trace import scope
+
 from .actsharding import constrain
 from .config import ModelConfig
 from .layers import (Params, attention, attention_decode, dense_init,
@@ -103,14 +105,16 @@ def _grouped_layer_scan(layers: Params, cfg: ModelConfig, x, group_fn,
 # ---------------------------------------------------------------------------
 def _block(cfg: ModelConfig, lp: Params, x, window, positions,
            mrope_positions, moe_dispatch: str):
-    h = attention(lp["attn"], rmsnorm(x, lp["ln1"]), cfg, window=window,
-                  positions=positions, mrope_positions=mrope_positions)
+    with scope("model/attn"):
+        h = attention(lp["attn"], rmsnorm(x, lp["ln1"]), cfg, window=window,
+                      positions=positions, mrope_positions=mrope_positions)
     x = x + h
-    z = rmsnorm(x, lp["ln2"])
-    if cfg.n_experts:
-        f = moe(lp["moe"], z, cfg, dispatch=moe_dispatch)
-    else:
-        f = mlp(lp["mlp"], z)
+    with scope("model/mlp"):
+        z = rmsnorm(x, lp["ln2"])
+        if cfg.n_experts:
+            f = moe(lp["moe"], z, cfg, dispatch=moe_dispatch)
+        else:
+            f = mlp(lp["mlp"], z)
     return x + f
 
 
@@ -122,9 +126,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array | None, *,
             remat: bool = True) -> jax.Array:
     """tokens (B, T) int32 or embeds (B, T, D) → logits (B, T, V)."""
     if embeds is None:
-        x = jnp.take(params["embed"], tokens, axis=0)
-        if cfg.family == "dense" and cfg.tie_embeddings:
-            x = x * (cfg.d_model ** 0.5)
+        with scope("model/embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)
+            if cfg.family == "dense" and cfg.tie_embeddings:
+                x = x * (cfg.d_model ** 0.5)
     else:
         x = embeds
     B, T, _ = x.shape
@@ -148,8 +153,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array | None, *,
                                      moe_dispatch))
             return x
 
-        x = _grouped_layer_scan(params["layers"], cfg, x, group_fn,
-                                remat=remat)
+        with scope("model/layers"):
+            x = _grouped_layer_scan(params["layers"], cfg, x, group_fn,
+                                    remat=remat)
     else:
         def body(x, inp):
             lp, _w = inp
@@ -157,13 +163,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array | None, *,
                                     mrope_positions, moe_dispatch)), None
 
         blk = jax.checkpoint(body) if remat else body
-        x, _ = lax.scan(blk, x, (params["layers"], wins))
-    x = rmsnorm(x, params["ln_f"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    if cfg.final_softcap > 0:
-        logits = (jnp.tanh(logits.astype(jnp.float32) / cfg.final_softcap)
-                  * cfg.final_softcap).astype(logits.dtype)
+        with scope("model/layers"):
+            x, _ = lax.scan(blk, x, (params["layers"], wins))
+    with scope("model/head"):
+        x = rmsnorm(x, params["ln_f"])
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = x @ head
+        if cfg.final_softcap > 0:
+            logits = (jnp.tanh(logits.astype(jnp.float32) / cfg.final_softcap)
+                      * cfg.final_softcap).astype(logits.dtype)
     return logits
 
 
@@ -172,10 +180,11 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: dict, **kw) -> jax.Array:
                      embeds=batch.get("embeds"),
                      mrope_positions=batch.get("mrope_positions"), **kw)
     labels = batch["labels"]
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0]
-    mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
-    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with scope("model/head"):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0]
+        mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
+        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.trace import scope
+
 Params = Any
 
 
@@ -48,37 +50,39 @@ def adamw_update(params: Params, grads: Params, opt_state: dict,
                  cfg: AdamWConfig, lr: jax.Array | float | None = None
                  ) -> tuple[Params, dict, jax.Array]:
     """Returns (new_params, new_state, grad_norm)."""
-    if cfg.grad_clip > 0:
-        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
-    else:
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
-    step = opt_state["step"] + 1
-    lr = cfg.lr if lr is None else lr
-    b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
+    with scope("optim/clip"):
+        if cfg.grad_clip > 0:
+            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+        else:
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(grads)))
+    with scope("optim/adamw"):
+        step = opt_state["step"] + 1
+        lr = cfg.lr if lr is None else lr
+        b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        gf = g.astype(jnp.float32)
-        m = cfg.b1 * m + (1 - cfg.b1) * gf
-        v = cfg.b2 * v + (1 - cfg.b2) * gf * gf
-        mh = m / b1c
-        vh = v / b2c
-        pf = p.astype(jnp.float32)
-        pf = pf - lr * (mh / (jnp.sqrt(vh) + cfg.eps)
-                        + cfg.weight_decay * pf)
-        return pf.astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            gf = g.astype(jnp.float32)
+            m = cfg.b1 * m + (1 - cfg.b1) * gf
+            v = cfg.b2 * v + (1 - cfg.b2) * gf * gf
+            mh = m / b1c
+            vh = v / b2c
+            pf = p.astype(jnp.float32)
+            pf = pf - lr * (mh / (jnp.sqrt(vh) + cfg.eps)
+                            + cfg.weight_decay * pf)
+            return pf.astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(opt_state["m"])
-    flat_v = jax.tree.leaves(opt_state["v"])
-    new_p, new_m, new_v = [], [], []
-    for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v):
-        a, b, c = upd(p, g, m, v)
-        new_p.append(a)
-        new_m.append(b)
-        new_v.append(c)
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(opt_state["m"])
+        flat_v = jax.tree.leaves(opt_state["v"])
+        new_p, new_m, new_v = [], [], []
+        for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v):
+            a, b, c = upd(p, g, m, v)
+            new_p.append(a)
+            new_m.append(b)
+            new_v.append(c)
     return (jax.tree.unflatten(tdef, new_p),
             {"m": jax.tree.unflatten(tdef, new_m),
              "v": jax.tree.unflatten(tdef, new_v),

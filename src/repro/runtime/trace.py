@@ -2,17 +2,29 @@
 
 Zero-dependency (stdlib only) so it imports without jax — the hot paths in
 `core/lower.py` and `core/bucketing.py` stay importable on machines with no
-accelerator stack.  The tracer records *nested spans* with monotonic clocks
-into a bounded ring buffer and exports them in the Chrome trace event
-format (``chrome://tracing`` / Perfetto "JSON array" flavor).
+accelerator stack.  Two kinds of names, one for each side of a jitted step:
 
-Design points, mirrored from the paper's measurement discipline:
+* ``scope(name)`` names the operations staged inside a ``jit`` /
+  ``shard_map`` body (the model's layers, the ZeRO-3 halves, the
+  optimizer, the schedule executor's steps, rounds and folds).  It is
+  ``jax.named_scope``: it writes only the ``op_name`` metadata of the
+  compiled program, so it costs nothing when the step runs, and a
+  profiler trace of the step carries the program's module, so each
+  device op can be put under the scope it came from.
+* ``Tracer.span(name)`` times host code (the planner, the numpy
+  interpreter, checkpoints, the input pipeline).  Whenever a JAX profiler
+  session is recording, the span is also a ``jax.profiler.TraceAnnotation``
+  of the same name, on the profiler's clock beside the device's ops, so
+  an idle gap of the device can be matched to what the host was doing.
+  That holds whether or not the ring below is enabled.
 
-* **Disabled by default.**  The default tracer starts disabled; every
-  instrumentation site pays one attribute load + one boolean check — the
-  same budget as the telemetry hub — so the <2 %% smoke-train-step
-  overhead gate (``benchmarks/telemetry_bench.py``) holds with the
-  instrumentation compiled in.
+The ring and its Chrome exporter are opt-in:
+
+* **Disabled by default.**  The default tracer starts disabled; a span
+  then costs one boolean check plus one call that asks whether a profiler
+  session is recording (a dictionary lookup before jax is imported), so
+  the <2 %% smoke-train-step overhead gate (``benchmarks/telemetry_bench.py``)
+  holds with the instrumentation compiled in.
 * **Monotonic clocks.**  ``time.perf_counter_ns`` only; wall time never
   enters a duration.
 * **Thread safety.**  The open-span *stack* is thread-local (spans nest
@@ -21,20 +33,14 @@ Design points, mirrored from the paper's measurement discipline:
   ``tid`` lane per thread.
 * **Ring-buffered.**  At most ``capacity`` finished spans are retained
   (oldest dropped), so a long training run cannot grow memory unboundedly.
-* **Sampling.**  ``sample_every=k`` keeps every k-th *root* span (children
-  of a dropped root are dropped with it) — deterministic, not random, so
-  traces are reproducible run to run.
 
-JAX caveat (documented, not hidden): spans placed *inside* ``shard_map`` /
-``jit`` bodies fire at **trace time**, when the python function is staged
-out, not at device execution time.  They are still exactly what a planner
-wants for attributing *structure* (which round, which fold, how many
-ppermutes) and for the interpreter paths (``run_numpy``), where durations
-are real.  Device-side wall time stays the telemetry hub's job.
+A span opened inside traced code would time the tracing, not the device:
+such sites use ``scope``.
 """
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -58,7 +64,7 @@ class Span:
 
 class _OpenSpan:
     """Context manager handed out by ``Tracer.span`` while recording."""
-    __slots__ = ("_tracer", "name", "t0", "args", "_stack")
+    __slots__ = ("_tracer", "name", "t0", "args", "_stack", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
@@ -66,14 +72,20 @@ class _OpenSpan:
         self.args = args
         self._stack = tracer._local_stack()
         self.t0 = 0
+        self._ann = None
 
     def __enter__(self) -> "_OpenSpan":
         self._stack.append(self)
+        if _recording():
+            self._ann = _Annotation(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._stack
         # pop self (exceptions can skip inner __exit__ only via interpreter
         # errors; defensively unwind to self)
@@ -84,7 +96,7 @@ class _OpenSpan:
 
 
 class _NullSpan:
-    """Shared no-op context manager for the disabled / sampled-out path."""
+    """Shared no-op context manager for the disabled path."""
     __slots__ = ()
 
     def __enter__(self) -> "_NullSpan":
@@ -97,22 +109,42 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _no_jax_yet() -> bool:
+    """Whether a JAX profiler session is recording. Until jaxlib's profiler
+    is imported none can be; once it is, `_recording` becomes its own
+    check and `_Annotation` the class behind `jax.profiler.TraceAnnotation`."""
+    global _recording, _Annotation
+    prof = sys.modules.get("jaxlib._profiler")
+    if prof is None:
+        return False
+    _Annotation = prof.TraceMe
+    _recording = prof.TraceMe.is_enabled
+    return _recording()
+
+
+_recording = _no_jax_yet
+_Annotation = None
+
+
+def scope(name: str):
+    """Name the operations staged inside the block: use inside a jitted or
+    `shard_map`'d body, where a host span would time the tracing. The
+    name lands in each op's `op_name` metadata; the program is otherwise
+    unchanged."""
+    import jax
+    return jax.named_scope(name)
+
+
 class Tracer:
     """Nested-span tracer with a bounded buffer and a Chrome exporter."""
 
-    def __init__(self, capacity: int = 65536, enabled: bool = False,
-                 sample_every: int = 1):
+    def __init__(self, capacity: int = 65536, enabled: bool = False):
         if capacity <= 0:
             raise ValueError("capacity must be positive")
-        if sample_every <= 0:
-            raise ValueError("sample_every must be positive")
         self.enabled = enabled
-        self.sample_every = sample_every
         self._spans: deque[Span] = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._tls = threading.local()
-        self._root_seen = 0          # root spans observed (for sampling)
-        self._dropped = 0            # spans discarded by sampling
 
     # -- recording ----------------------------------------------------------
     def _local_stack(self) -> list:
@@ -122,26 +154,13 @@ class Tracer:
         return stack
 
     def span(self, name: str, **args):
-        """Open a span: ``with tracer.span("plan/lookup", level=lvl): ...``
+        """Open a host span: ``with tracer.span("plan/lookup", level=lvl):``
 
-        Returns a shared null context manager when disabled, or when this
-        thread's current *root* span was sampled out.
+        Disabled, it is a profiler annotation of the same name while a JAX
+        profiler session records, and a shared null context otherwise.
         """
         if not self.enabled:
-            return _NULL_SPAN
-        if getattr(self._tls, "skip_depth", 0):
-            # inside a sampled-out root: drop the whole subtree
-            self._dropped += 1
-            return _NULL_SPAN
-        stack = self._local_stack()
-        if not stack:                 # root span: apply sampling decision
-            with self._lock:
-                keep = (self._root_seen % self.sample_every) == 0
-                self._root_seen += 1
-            if not keep:
-                self._tls.skip_depth = 1
-                self._dropped += 1
-                return _SkipSpan(self)
+            return _Annotation(name) if _recording() else _NULL_SPAN
         return _OpenSpan(self, name, args or None)
 
     def _finish(self, open_span: _OpenSpan, t1: int, depth: int) -> None:
@@ -167,15 +186,9 @@ class Tracer:
         with self._lock:
             return list(self._spans)
 
-    @property
-    def dropped(self) -> int:
-        return self._dropped
-
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
-            self._root_seen = 0
-            self._dropped = 0
 
     # -- export -------------------------------------------------------------
     def to_chrome(self, process_name: str = "repro") -> list[dict]:
@@ -215,22 +228,6 @@ class Tracer:
             json.dump({"traceEvents": events,
                        "displayTimeUnit": "ms"}, f)
         return len(events)
-
-
-class _SkipSpan:
-    """Root-span placeholder while its subtree is sampled out."""
-    __slots__ = ("_tracer",)
-
-    def __init__(self, tracer: Tracer):
-        self._tracer = tracer
-
-    def __enter__(self) -> "_SkipSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        tls = self._tracer._tls
-        tls.skip_depth = max(0, getattr(tls, "skip_depth", 1) - 1)
-        return None
 
 
 # ---------------------------------------------------------------------------

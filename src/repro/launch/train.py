@@ -50,6 +50,18 @@ def _note_sync_fallback(what: str, err) -> None:
     print(f"fallback: {what} ({err!r})", file=sys.stderr, flush=True)
 
 
+def _attention_paths() -> str:
+    """The trace-time count of attention calls by the path they can take
+    (`models/layers.py`: splash kernels on a TPU, or XLA)."""
+    from repro.models.layers import attention_path_metric
+    from repro.runtime.metrics import default_metrics
+    snap = default_metrics().snapshot()
+    counts = " ".join(
+        f"{p}={snap.get(attention_path_metric(p), {}).get('value', 0):g}"
+        for p in ("kernel", "xla"))
+    return f"attention paths (calls traced): {counts}"
+
+
 # ---------------------------------------------------------------------------
 # auto engine (pjit)
 # ---------------------------------------------------------------------------
@@ -561,6 +573,8 @@ def run_training(tc: TrainConfig, mesh: Mesh | None = None,
             loss = float(metrics["loss"])     # waits for the step
         step_seconds.append(_time.perf_counter() - t0)
         step_hist.observe(step_seconds[-1])
+        if not losses:                  # the step is traced and compiled
+            on_log(_attention_paths())
         if step % tc.log_every == 0:
             on_log(f"step {step:5d}  loss {loss:.4f}  "
                    f"gnorm {float(metrics['gnorm']):.3f}")

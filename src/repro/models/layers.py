@@ -6,8 +6,10 @@ Attention never materializes a (T, T) score tensor: the train/prefill path
 scans over query blocks (online softmax against the full K for global
 layers; a banded KV slice for sliding-window layers, making local layers
 O(T·W)). This is the flash algorithm expressed in XLA ops so it lowers on
-any backend; the Pallas kernel (kernels/flash_attention.py) is the
-TPU-native variant selected with impl="pallas".
+any backend. Causal global self-attention lowered for a TPU instead runs
+JAX's splash attention kernels (forward and backward, masked blocks
+skipped, K/V shared by each GQA group); `attention_path_total` counts
+which of the two each traced call can take.
 """
 from __future__ import annotations
 
@@ -142,6 +144,72 @@ def _sdpa_block(q, k, v, mask, scale, softcap, remask: bool = True):
     return o, m, l
 
 
+# Query and KV block of every splash kernel: a sequence it does not
+# divide stays on the XLA branch.
+SPLASH_BLOCK = 512
+
+
+def _takes_kernel(cfg: ModelConfig, T: int, Tk: int, window, causal: bool,
+                  kv_override) -> bool:
+    """Whether a call is causal global self-attention that the splash
+    kernels compute exactly, in a program XLA does not partition over
+    several devices (a Mosaic kernel cannot be; shard_map bodies and
+    one-device programs can run it): every other call keeps its XLA
+    branch."""
+    from .actsharding import mesh_ctx
+    ctx = mesh_ctx()
+    return (causal and isinstance(window, int) and window == 0
+            and cfg.attn_softcap == 0 and kv_override is None and Tk == T
+            and T % SPLASH_BLOCK == 0 and cfg.n_heads % cfg.n_kv_heads == 0
+            and not cfg.attn_kv_block and (ctx is None or ctx[0].size == 1))
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(T: int, n_heads: int, interpret: bool):
+    """The causal multi-head splash kernel for T tokens, built once: its
+    mask's block structure is analysed here, not at every trace."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    b = SPLASH_BLOCK
+    sizes = sa.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
+                          block_q_dkv=b, block_kv_dkv=b,
+                          block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    mask = sa.MultiHeadMask([sa.CausalMask((T, T))] * n_heads)
+    with jax.ensure_compile_time_eval():    # concrete mask info, no tracers
+        return sa.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def splash_attention(q, k, v, scale: float, interpret: bool = False):
+    """Causal attention through JAX's splash kernels. q: (B, H, T, D);
+    k, v: (B, Hkv, T, D) with Hkv dividing H, shared by each group of H/Hkv
+    query heads without a copy. bf16 operands, f32 accumulation and
+    softmax statistics; the kernel takes no scale, so q carries it."""
+    kernel = _splash_kernel(q.shape[2], q.shape[1], interpret)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    return jax.vmap(kernel)(q, k, v)
+
+
+def attention_path_metric(path: str) -> str:
+    """The counter of traced attention calls that can take `path`
+    ("kernel" or "xla"); a scanned stack of layers is one traced call."""
+    return f'attention_path_total{{path="{path}"}}'
+
+
+def _count_path(kernel: bool) -> None:
+    from repro.runtime.metrics import default_metrics
+    default_metrics().counter(
+        attention_path_metric("kernel" if kernel else "xla"),
+        "attention calls traced, by the path their inputs allow").inc()
+
+
+def _causal_global(q, k, v, scale: float, dtype, xla):
+    """The splash kernels where the program is lowered for a TPU, `xla`
+    (the q-block scan) on every other platform."""
+    return lax.platform_dependent(
+        q, k, v, default=xla,
+        tpu=lambda q, k, v: splash_attention(q, k, v, scale).astype(dtype))
+
+
 def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
               window: jax.Array | int = 0, causal: bool = True,
               positions: jax.Array | None = None,
@@ -150,7 +218,8 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     """Full-sequence attention (train / prefill), q-block scanned.
 
     window: static int (banded path when > 0) or traced scalar (masked
-    path — used under scan over heterogeneous layers).
+    path — used under scan over heterogeneous layers). Causal global
+    self-attention (`_takes_kernel`) runs the splash kernels on a TPU.
     """
     B, T, _ = x.shape
     if positions is None:
@@ -161,18 +230,55 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     hd = cfg.head_dim
     scale = hd ** -0.5
     rep = cfg.n_heads // cfg.n_kv_heads
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
     Tk = k.shape[2]
+    static_window = isinstance(window, int)
+    kernel = _takes_kernel(cfg, T, Tk, window, causal, kv_override)
+    _count_path(kernel)
+
+    def repeat_kv(k, v):
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=1)
+            v = jnp.repeat(v, rep, axis=1)
+        return k, v
+
+    if not kernel:
+        k, v = repeat_kv(k, v)
 
     bq = min(block_q, T)
     if T % bq:
         bq = T  # fallback: single block
     nq = T // bq
 
-    static_window = isinstance(window, int)
-    if static_window and window > 0 and causal and Tk == T and window < T:
+    def global_scan(q, k, v):
+        # ---- q-block scan against full K (global layers) -----------------
+        def body(carry, qi):
+            qb = lax.dynamic_slice_in_dim(q, qi * bq, bq, axis=2)
+            qpos = qi * bq + jnp.arange(bq)[:, None] + (Tk - T)
+            kpos = jnp.arange(Tk)[None, :]
+            mask = jnp.ones((bq, Tk), bool)
+            if causal:
+                mask &= kpos <= qpos
+            w = window
+            if not static_window:
+                mask &= (w <= 0) | (kpos > qpos - w)
+            elif w > 0:
+                mask &= kpos > qpos - w
+            o, m, l = _sdpa_block(qb, k, v, mask[None, None],
+                                  scale, cfg.attn_softcap,
+                                  remask=not causal)
+            return carry, (o / (l + 1e-30)).astype(x.dtype)
+
+        # nested remat: without it the backward stacks each q-block's (bq, Tk)
+        # probability matrix as scan residuals (9.2 TB/device measured on
+        # mixtral×train_4k); recompute from (q,k,v) instead.
+        _, outs = lax.scan(jax.checkpoint(body), None, jnp.arange(nq))
+        return jnp.moveaxis(outs, 0, 2).reshape(B, cfg.n_heads, T, hd)
+
+    if kernel:
+        out = _causal_global(q, k, v, scale, x.dtype,
+                             lambda q, k, v: global_scan(q, *repeat_kv(k, v)))
+    elif static_window and window > 0 and causal and Tk == T \
+            and window < T:
         # ---- banded path: each q block sees [start, start+span) of KV ----
         span = min(bq + (window // bq + 1) * bq, Tk)
 
@@ -236,29 +342,7 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
         _, outs = lax.scan(jax.checkpoint(q_body), None, jnp.arange(nq))
         out = jnp.moveaxis(outs, 0, 2).reshape(B, cfg.n_heads, T, hd)
     else:
-        # ---- q-block scan against full K (global layers) -----------------
-        def body(carry, qi):
-            qb = lax.dynamic_slice_in_dim(q, qi * bq, bq, axis=2)
-            qpos = qi * bq + jnp.arange(bq)[:, None] + (Tk - T)
-            kpos = jnp.arange(Tk)[None, :]
-            mask = jnp.ones((bq, Tk), bool)
-            if causal:
-                mask &= kpos <= qpos
-            w = window
-            if not static_window:
-                mask &= (w <= 0) | (kpos > qpos - w)
-            elif w > 0:
-                mask &= kpos > qpos - w
-            o, m, l = _sdpa_block(qb, k, v, mask[None, None],
-                                  scale, cfg.attn_softcap,
-                                  remask=not causal)
-            return carry, (o / (l + 1e-30)).astype(x.dtype)
-
-        # nested remat: without it the backward stacks each q-block's (bq, Tk)
-        # probability matrix as scan residuals (9.2 TB/device measured on
-        # mixtral×train_4k); recompute from (q,k,v) instead.
-        _, outs = lax.scan(jax.checkpoint(body), None, jnp.arange(nq))
-        out = jnp.moveaxis(outs, 0, 2).reshape(B, cfg.n_heads, T, hd)
+        out = global_scan(q, k, v)
 
     out = out.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * hd)
     return out @ p["wo"]

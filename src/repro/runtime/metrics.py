@@ -14,7 +14,10 @@ Exporters:
   ingest it without code.
 
 Naming convention: ``component_noun_unit`` with underscores, e.g.
-``plan_cache_hits_total``, ``bucket_pipeline_occupancy``.
+``plan_cache_hits_total``, ``bucket_pipeline_occupancy``. A counter or
+gauge may carry a label set in its name, as Prometheus writes it
+(``attention_path_total{path="kernel"}``): each label set is a metric of
+its own, and the exposition types the family once.
 """
 from __future__ import annotations
 
@@ -177,19 +180,21 @@ class MetricsRegistry:
     def to_prometheus(self) -> str:
         """Prometheus text exposition format (0.0.4)."""
         lines: list[str] = []
+        typed: set[str] = set()
         with self._lock:
             metrics = list(self._metrics.values())
         for m in sorted(metrics, key=lambda m: m.name):
-            if m.help:
-                lines.append(f"# HELP {m.name} {m.help}")
-            if isinstance(m, Counter):
-                lines.append(f"# TYPE {m.name} counter")
-                lines.append(f"{m.name} {_fmt(m.value)}")
-            elif isinstance(m, Gauge):
-                lines.append(f"# TYPE {m.name} gauge")
+            family = m.name.split("{")[0]
+            if family not in typed:
+                typed.add(family)
+                if m.help:
+                    lines.append(f"# HELP {family} {m.help}")
+                kind = {Counter: "counter", Gauge: "gauge"}.get(
+                    type(m), "histogram")
+                lines.append(f"# TYPE {family} {kind}")
+            if isinstance(m, (Counter, Gauge)):
                 lines.append(f"{m.name} {_fmt(m.value)}")
             else:
-                lines.append(f"# TYPE {m.name} histogram")
                 for bound, c in m.cumulative():
                     le = "+Inf" if bound == float("inf") else _fmt(bound)
                     lines.append(f'{m.name}_bucket{{le="{le}"}} {c}')

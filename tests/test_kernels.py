@@ -1,5 +1,9 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracles,
 swept over shapes and dtypes (as required for every kernel in kernels/)."""
+import hashlib
+import re
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,6 +116,207 @@ def test_flash_cross_attention_lengths():
     want = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# splash attention — the trainer's causal global attention on a TPU
+# ---------------------------------------------------------------------------
+def _attn_cfg(n_kv_heads=4, d_head=64, **kw):
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models.config import smoke_config
+    return dataclasses.replace(smoke_config(get_config("stablelm-12b")),
+                               n_heads=4, n_kv_heads=n_kv_heads,
+                               d_head=d_head, **kw)
+
+
+def _kernel_in_interpret_mode(monkeypatch):
+    """Where the program is lowered for a TPU, `attention` takes the splash
+    path: run that path here, with the kernels interpreted."""
+    from repro.models import layers
+    monkeypatch.setattr(
+        layers, "_causal_global",
+        lambda q, k, v, scale, dtype, xla: layers.splash_attention(
+            q, k, v, scale, interpret=True).astype(dtype))
+
+
+def _rel(got, want) -> float:
+    got, want = (np.asarray(a, np.float32).ravel() for a in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# bf16 carries 8 significant bits (unit roundoff 2^-9 ~ 2e-3). Against
+# the XLA branch, which keeps the probabilities P and the output in f32 up
+# to the last cast, the kernel rounds P to bf16 before P.V and rounds q
+# once more after the scale: relative errors of a few unit roundoffs
+# (up to 6e-3 measured), so the bound is 1.5e-2 on every array.
+SPLASH_REL_TOL = 1.5e-2
+
+
+@pytest.mark.parametrize("T", [512, 1024])
+@pytest.mark.parametrize("d_head", [64, 160])
+@pytest.mark.parametrize("n_kv_heads", [1, 4], ids=["gqa4to1", "mha"])
+def test_splash_path_matches_xla_branch(monkeypatch, n_kv_heads, d_head, T):
+    from repro.models.layers import attention, init_attention
+    cfg = _attn_cfg(n_kv_heads, d_head)
+    p = init_attention(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, T, cfg.d_model),
+                          jnp.float32).astype(jnp.bfloat16)
+    ct = jax.random.normal(jax.random.PRNGKey(2), (1, T, cfg.d_model),
+                           jnp.float32)
+
+    def run():
+        def loss(p, x):
+            y = attention(p, x, cfg).astype(jnp.float32)
+            return (y * ct).sum(), y
+        (_, y), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return y, g
+
+    want_y, (want_gp, want_gx) = run()
+    _kernel_in_interpret_mode(monkeypatch)
+    got_y, (got_gp, got_gx) = run()
+    errs = {"out": _rel(got_y, want_y), "x": _rel(got_gx, want_gx)}
+    errs |= {w: _rel(got_gp[w], want_gp[w])
+             for w in ("wq", "wk", "wv", "wo")}
+    assert max(errs.values()) < SPLASH_REL_TOL, errs
+
+
+def _canonical_hlo(hlo: str) -> list[list[str]]:
+    """Optimized HLO without metadata, each instruction named by what it
+    computes (its op, attributes and operands' names, recursively), each
+    computation's lines sorted: equal for programs that differ only in the
+    order XLA printed independent instructions."""
+    hlo = re.sub(r", metadata=\{[^}\n]*\}", "", hlo)
+    names: dict[str, str] = {}
+    comps, body, header = [], None, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        inst = re.match(r"\s+(ROOT )?(%[\w.\-]+) = (.*)$", line)
+        if head:
+            header, body = head.group(2), []
+        elif inst and body is not None:
+            root, name, rest = inst.groups()
+            line = (root or "") + re.sub(
+                r"%[\w.\-]+", lambda m: names.get(m.group(0), "%?"), rest)
+            names[name] = "%" + hashlib.sha1(line.encode()).hexdigest()[:16]
+            body.append(line)
+        elif line == "}" and body is not None:
+            body.sort()
+            names[header] = "%" + hashlib.sha1(
+                "\n".join(body).encode()).hexdigest()[:16]
+            comps.append(body)
+            body = None
+    return sorted(comps)
+
+
+# (name, config changes, attention keywords, T): the causal global call
+# the kernel takes, then each kind of call that keeps its XLA branch
+ATTENTION_CALLS = [
+    ("causal_global", {}, {}, 512),
+    ("non_causal", {}, {"causal": False}, 512),
+    ("static_window", {}, {"window": 64}, 512),
+    ("traced_window", {}, {"window": "traced"}, 512),
+    ("softcap", {"attn_softcap": 30.0}, {}, 512),
+    ("kv_override", {}, {"kv_override": "ones"}, 512),
+    ("ragged_T", {}, {}, 96),
+    ("kv_block", {"attn_kv_block": 128}, {}, 512),
+    ("partitioned_over_4_devices", {}, {}, 512),
+]
+
+
+def _attention_call(case):
+    from repro.models.layers import attention, init_attention
+    _, changes, kw, T = case
+    cfg = _attn_cfg(**changes)
+    p = init_attention(jax.random.PRNGKey(0), cfg)
+    x = jnp.ones((1, T, cfg.d_model), jnp.bfloat16)
+    kv = jnp.ones((1, cfg.n_kv_heads, T, cfg.head_dim), jnp.bfloat16)
+    kw = {k: {"traced": jnp.int32(0), "ones": (kv, kv)}.get(v, v)
+          if isinstance(v, str) else v for k, v in kw.items()}
+    if case[0] != "partitioned_over_4_devices":
+        return (lambda p, x: attention(p, x, cfg, **kw)), p, x
+
+    def partitioned(p, x):
+        """As traced under the auto engine's hook for a 4-device mesh."""
+        from repro.models import actsharding
+        was = actsharding._HOOK, actsharding._MESH
+        actsharding.set_hook(None, types.SimpleNamespace(
+            size=4, axis_names=("data", "model")))
+        try:
+            return attention(p, x, cfg)
+        finally:
+            actsharding.set_hook(*was)
+    return partitioned, p, x
+
+
+@pytest.mark.parametrize("case", ATTENTION_CALLS, ids=lambda c: c[0])
+def test_attention_on_cpu_lowers_the_xla_branch(monkeypatch, case):
+    """On the CPU every call lowers to the XLA branch alone, forward and
+    gradient: the same program as with the platform dispatch taken out.
+    Only causal global self-attention reaches the dispatch at all."""
+    from repro.models import layers
+    call, p, x = _attention_call(case)
+
+    def programs():
+        grad = jax.grad(lambda p, x: jax.checkpoint(call)(p, x).astype(
+            jnp.float32).sum(), argnums=(0, 1))
+        return [_canonical_hlo(jax.jit(f).lower(p, x).compile().as_text())
+                for f in (call, grad)]
+
+    dispatched = []
+    as_is = layers._causal_global
+
+    def spy(*a):
+        dispatched.append(case[0])
+        return as_is(*a)
+
+    monkeypatch.setattr(layers, "_causal_global", spy)
+    got = programs()
+    assert dispatched == ([case[0]] * 2 if case[0] == "causal_global"
+                          else [])
+    monkeypatch.setattr(layers, "_causal_global",
+                        lambda q, k, v, scale, dtype, xla: xla(q, k, v))
+    assert got == programs()
+
+
+def test_canonical_hlo_sees_a_changed_program():
+    f = jax.jit(lambda x: jnp.exp(x) + 1.0)
+    g = jax.jit(lambda x: jnp.exp(x) + 2.0)
+    x = jnp.ones((8,))
+    text = f.lower(x).compile().as_text()
+    assert _canonical_hlo(text) == _canonical_hlo(text)
+    assert _canonical_hlo(text) != _canonical_hlo(
+        g.lower(x).compile().as_text())
+
+
+def test_attention_path_total_counts_traced_calls():
+    from repro.models.layers import attention_path_metric
+    from repro.models.registry import build
+    from repro.runtime.metrics import MetricsRegistry, set_default_metrics
+    reg = MetricsRegistry()
+    old = set_default_metrics(reg)
+    try:
+        for case in ATTENTION_CALLS:
+            jax.eval_shape(*_attention_call(case))
+        snap = reg.snapshot()
+        assert snap[attention_path_metric("kernel")]["value"] == 1
+        assert (snap[attention_path_metric("xla")]["value"]
+                == len(ATTENTION_CALLS) - 1)
+        # a scanned stack of layers is one traced call, whatever its depth
+        reg.clear()
+        api = build(_attn_cfg(n_layers=3))
+        toks = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+        jax.eval_shape(lambda p, t: api.loss_fn(p, {"tokens": t,
+                                                    "labels": t}),
+                       api.params_spec(), toks)
+        assert reg.snapshot() == {attention_path_metric("kernel"): {
+            "type": "counter", "value": 1.0}}
+        prom = reg.to_prometheus()
+        assert prom.count("# TYPE attention_path_total counter") == 1
+        assert 'attention_path_total{path="kernel"} 1' in prom
+    finally:
+        set_default_metrics(old)
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import pytest
 
@@ -123,3 +124,41 @@ def test_planned_allreduce_compiles_to_collective_permutes(topo):
     text = _compile(f, x).as_text()
     assert "collective-permute" in text
     assert "all-reduce" not in text   # the plan, not a flat psum
+
+
+def test_splash_attention_compiles_at_the_cell_shape(one_chip):
+    """The stablelm-12b cell's attention layer (B=1, T=4096, 32 query and
+    8 KV heads of 160, bf16), forward and gradient under jax.checkpoint
+    and the `model/attn` scope: the splash forward, dq and dkv kernels,
+    each named under `model/attn`, in less scratch memory than the XLA
+    branch's 0.74 GiB."""
+    from repro.configs import get_config
+    from repro.models.layers import attention, init_attention
+    from repro.runtime.trace import scope
+
+    cfg = get_config("stablelm-12b")
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (32, 8, 160)
+    p = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_attention(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((1, 4096, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def layer(p, x):
+        with scope("model/attn"):
+            return attention(p, x, cfg)
+
+    compiled = _compile(jax.grad(
+        lambda p, x: jax.checkpoint(layer)(p, x).astype(jnp.float32).sum(),
+        argnums=(0, 1)), p, x)
+    text = compiled.as_text()
+    op_names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
+        flags=re.S)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert sorted(re.search(r"splash_mha_\w+", n).group(0)
+                  for n in op_names) == [
+        "splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+        "splash_mha_fwd_residuals"]
+    assert all("/model/attn/" in n for n in op_names), op_names
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * (1 << 30)

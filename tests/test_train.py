@@ -23,6 +23,24 @@ def test_auto_engine_loss_decreases(tmp_path):
     assert losses[-1] < losses[0] - 0.3, (losses[0], losses[-1])
 
 
+@pytest.mark.parametrize("seq_len,paths", [(512, "kernel=1 xla=0"),
+                                            (64, "kernel=0 xla=1")])
+def test_trainer_prints_attention_paths_once(seq_len, paths):
+    """After its first step the trainer names the path its attention can
+    take: the splash kernels where 512 divides the sequence."""
+    from repro.runtime.metrics import MetricsRegistry, set_default_metrics
+    logs = []
+    old = set_default_metrics(MetricsRegistry())
+    try:
+        run_training(TrainConfig(arch="stablelm-12b", steps=2,
+                                 seq_len=seq_len, global_batch=1,
+                                 log_every=1000), on_log=logs.append)
+    finally:
+        set_default_metrics(old)
+    assert [s for s in logs if s.startswith("attention paths")] == [
+        f"attention paths (calls traced): {paths}"]
+
+
 def test_ckpt_restart_replays_exactly(tmp_path):
     tc = dict(arch="stablelm-12b", steps=20, seq_len=32, global_batch=2,
               lr=1e-3, ckpt_every=10, log_every=1000)

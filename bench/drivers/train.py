@@ -56,9 +56,16 @@ def fallbacks() -> float:
 def model_config(config: dict):
     """The program's ModelConfig for the configuration file: its
     registered architecture, with every size the file's `program` map
-    names taken from the file."""
+    names taken from the file; a key `published.<key>` names that key of
+    the file's `published` sizes (such as all the experts a router
+    chooses from, where the chip holds a share of them)."""
     from repro.configs import get_config
-    fields = {f: config[k] for f, k in config["program"].items()}
+
+    def size(key):
+        if key.startswith("published."):
+            return config["published"][key.removeprefix("published.")]
+        return config[key]
+    fields = {f: size(k) for f, k in config["program"].items()}
     return dataclasses.replace(get_config(config["arch"]), **fields)
 
 
@@ -307,6 +314,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         "setup_s": setup_s,
         "input_s": tn.input_s[n_input:],
         "flops_per_token": counts.model_flops_per_token(tn.config, tn.T),
+        # one chip's attention core a step, for the kernels' roofline
+        "attention_core": counts.attention_core(
+            tn.config, tn.tr["sequences_per_chip"], tn.T),
         "chips": tn.n,
         "peaks": peaks(devs[0].device_kind) if devs[0].platform == "tpu"
         else None,

@@ -4,7 +4,8 @@ found by name, the chip check, the caches, and the result line.
 A cell (an entry of `workloads` in BENCHMARK.json) is found by its name:
 
   bench/workloads/<cell>.json     its traffic and the driver that runs it
-  bench/configs/<config>.json     the model configuration as it is run
+  bench/configs/<config>.json     the model configuration as it is run, and
+                                  its `tiny` sizes for the CPU tests
   bench/configs/<config>.ref.py   its plain reference (imports no program code)
   bench/drivers/<kind>.py         the driver named by the workload's `kind`
   bench/metrics/<metric>.py       one per-layer metric: `read(run) -> float|None`
@@ -87,6 +88,15 @@ def cell(name: str, root: Path = ROOT) -> dict:
         "end_to_end": e2e,
         "per_layer": per_layer,
     }
+
+
+def at_test_size(config: dict) -> dict:
+    """The configuration at the size the CPU tests run it: the file's
+    `tiny` sizes laid over it, those under `tiny.published` over its
+    `published` sizes."""
+    tiny = dict(config["tiny"])
+    published = {**config["published"], **tiny.pop("published", {})}
+    return {**config, **tiny, "published": published}
 
 
 def metric_reader(name: str, root: Path = ROOT):

@@ -1,8 +1,9 @@
-"""The comparison that decides `correct`, at a size a test run holds, on
-the CPU: the harness's look for a chip is skipped and the rest of a run is
-driven with the timed path broken underneath; each fault that a cell can
-have, and the control (the reference in float8 put in the program's
-place), must come out not correct under the cell's own limits."""
+"""The comparison that decides `correct`, at a size a test run holds (the
+configuration file's `tiny` sizes), on the CPU: the harness's look for a
+chip is skipped and the rest of a run is driven with the timed path
+broken underneath; each fault that a cell can have, and the control (the
+reference in float8 put in the program's place), must come out not
+correct under the cell's own limits."""
 from __future__ import annotations
 
 import copy
@@ -20,18 +21,13 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import harness  # noqa: E402
 
-TINY = {
-    "dense": dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-                  head_dim=16, intermediate_size=128, vocab_size=512,
-                  num_hidden_layers=2),
-}
 CELLS = [w["name"] for w in json.loads(
     (ROOT / "BENCHMARK.json").read_text())["workloads"] if w["chips"] == 1]
 
 
 def tiny(name: str) -> dict:
     c = copy.deepcopy(harness.cell(name))
-    c["config"].update(TINY[c["config"]["family"]])
+    c["config"] = harness.at_test_size(c["config"])
     c["workload"]["traffic"]["seq_len"] = 64
     return c
 
@@ -82,11 +78,8 @@ def test_rwkv6_reference_follows_the_program():
     program's one-device steps at a tiny size: the same losses to bf16
     rounding, and gradients of the same norms."""
     c = tiny(CELLS[0])
-    c["config"] = json.loads(
-        (BENCH / "configs" / "rwkv6-1.6b.json").read_text())
-    c["config"].update(hidden_size=64, num_attention_heads=4, head_size=16,
-                       intermediate_size=128, vocab_size=512,
-                       num_hidden_layers=2)
+    c["config"] = harness.at_test_size(json.loads(
+        (BENCH / "configs" / "rwkv6-1.6b.json").read_text()))
     c["reference"] = BENCH / "configs" / "rwkv6-1.6b.ref.py"
     c["workload"]["traffic"]["seq_len"] = 128
     out = drive(c)

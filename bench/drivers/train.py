@@ -16,6 +16,14 @@ moved during it. With `trace`, `trace_steps` more steps run under the
 profiler after the window. Once the window has closed and the device
 memory peak is read, the program's state is freed and the reference
 takes the same first steps.
+
+The record holds what a metric file needs to read a configuration's own
+mechanisms without an edit here: the configuration file as run
+(`config`), the workload's `traffic`, the program's `ModelConfig`
+(`model_config`, logged at set-up too, inherited fields with the rest),
+the window's change of each program counter and the last value of each
+gauge (`counters`, `runtime/metrics.py` names), and every scalar of the
+last window step's outputs but its loss (`step_outputs`).
 """
 from __future__ import annotations
 
@@ -51,6 +59,18 @@ def fallbacks() -> float:
     for _, st in guard_stats():
         total += sum(st.get(k, 0) for k in FALLBACK_STATS)
     return total
+
+
+def window_counters(before: dict, after: dict) -> dict:
+    """Each counter's change from `before` to `after` and each gauge's
+    value at `after`, both `default_metrics().snapshot()`s."""
+    out = {}
+    for name, m in after.items():
+        if m["type"] == "counter":
+            out[name] = m["value"] - before.get(name, {}).get("value", 0.0)
+        elif m["type"] == "gauge":
+            out[name] = m["value"]
+    return out
 
 
 def model_config(config: dict):
@@ -176,6 +196,7 @@ class Trainer:
         with jax.profiler.TraceAnnotation("bench/loss_read"):
             loss = float(metrics["loss"])
         t3 = time.perf_counter()
+        self.outputs = metrics
         self.input_s.append(t1 - t0)
         self.step_s.append(t3 - t0)
         self.phases.append((self.step, t1 - t0, t2 - t1, t3 - t2,
@@ -233,6 +254,11 @@ class Trainer:
             for s in range(self.wl["check_steps"])
             for k, v in self.stream.batch_at(s).items()))
 
+    def step_outputs(self) -> dict:
+        """Every scalar the last step returned but its loss, read once."""
+        return {k: float(v) for k, v in self.outputs.items()
+                if k != "loss" and np.ndim(v) == 0}
+
     def free(self) -> None:
         self.state = None
         gc.collect()
@@ -279,11 +305,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         t_start: float, devices=None, fault: str | None = None) -> dict:
     import jax
     import harness
+    from repro.runtime.metrics import default_metrics
 
     devs = devices if devices is not None else harness.chips(
         cell["entry"]["chips"])
     clock = CompileClock()
     tn = Trainer(cell, devs, fault)
+    model = dataclasses.asdict(tn.cfg)
+    log(f"model config: {model}")
     tn.start(seed)
     got, setup_failed = tn.first_steps()
     compiles_setup, traces_setup = clock.compiles, clock.traces
@@ -292,6 +321,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         f"misses; losses {got['losses']}; failed steps {setup_failed}")
 
     # -- the window --
+    counters_before = default_metrics().snapshot()
     t_w = time.perf_counter()
     setup_s = t_w - t_start
     n_input = len(tn.input_s)
@@ -301,8 +331,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         attempted += 1
         failed += tn.counted_step()[1]
     window_s = time.perf_counter() - t_w
+    counters = window_counters(counters_before, default_metrics().snapshot())
+    step_outputs = tn.step_outputs()
     log_steps(tn.step_s[n_input:], tn.input_s[n_input:], host_before,
               host_load(), tn.phases[n_input:])
+    log(f"window counters: {counters}; last step's outputs: {step_outputs}")
     in_window = clock.compiles - compiles_setup
     if in_window or clock.traces > traces_setup:
         log(f"warning: inside the window {in_window} programs compiled and "
@@ -320,6 +353,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         "chips": tn.n,
         "peaks": peaks(devs[0].device_kind) if devs[0].platform == "tpu"
         else None,
+        "config": tn.config,
+        "traffic": tn.tr,
+        "model_config": model,
+        "counters": counters,
+        "step_outputs": step_outputs,
     }
 
     if trace:

@@ -23,7 +23,15 @@ scope counts to the outermost executor scope. Each op's phase is read
 from its path: recompute under `rematted_computation`, backward under
 `transpose(`, forward otherwise.
 
-The program's host spans (`data/*`, written by `runtime/trace.py` as
+A configuration file may list `scopes`: names its program opens inside a
+layer scope (`model/mlp/experts`). They are matched before the fixed
+names, the longest first, and an op under one goes to that name in
+`by_scope`; its layer, and so `by_layer`, `unscoped_s` and `op_s`, is the
+same whether the names are listed or not. A listed name outside every
+layer scope is not a scope.
+
+The program's host spans (`data/*`, and the prefixes a configuration
+file lists under `program_spans`, written by `runtime/trace.py` as
 profiler annotations) are loaded beside the bench's own.
 """
 from __future__ import annotations
@@ -35,10 +43,10 @@ from pathlib import Path
 import harness
 import tracing
 
-SCOPE = re.compile(
-    r"(?<![\w.])(model/(?:embed|layers|attn|mlp|head)"
-    r"|zero3/(?:gather|scatter)|optim/(?:clip|adamw)"
-    r"|(?:exec|bucket|sync|overlap)/[a-z0-9_]+(?:/step\d+)?)(?!\w)")
+FIXED = (r"model/(?:embed|layers|attn|mlp|head)"
+         r"|zero3/(?:gather|scatter)|optim/(?:clip|adamw)"
+         r"|(?:exec|bucket|sync|overlap)/[a-z0-9_]+(?:/step\d+)?")
+SCOPE = re.compile(rf"(?<![\w.])({FIXED})(?!\w)")
 LAYER = ("model/", "zero3/", "optim/")
 MODULES_LINE = "XLA Modules"
 METADATA_PLANE = "/host:metadata"
@@ -141,13 +149,26 @@ def op_names(xspace) -> dict[str, dict[str, str]]:
 # ---------------------------------------------------------------------------
 # ops under scopes
 # ---------------------------------------------------------------------------
-def scopes_of(op_name: str) -> tuple[str | None, str | None]:
-    """(innermost scope, layer) of an op's path; (None, None) unscoped."""
+def scope_pattern(listed=()) -> re.Pattern:
+    """SCOPE with a configuration file's own scope names before the fixed
+    ones, the longest first."""
+    if not listed:
+        return SCOPE
+    names = "|".join(re.escape(n) for n in sorted(set(listed), key=len,
+                                                  reverse=True))
+    return re.compile(rf"(?<![\w.])({names}|{FIXED})(?!\w)")
+
+
+def scopes_of(op_name: str, pattern: re.Pattern = SCOPE
+              ) -> tuple[str | None, str | None]:
+    """(innermost scope, layer) of an op's path; (None, None) unscoped.
+    The layer is read by the fixed names alone; `pattern` may add names
+    nested in it (`scope_pattern`)."""
     found = SCOPE.findall(op_name)
     if not found:
         return None, None
     layers = [s for s in found if s.startswith(LAYER)]
-    return found[-1], (layers[-1] if layers else found[0])
+    return pattern.findall(op_name)[-1], (layers[-1] if layers else found[0])
 
 
 def phase(op_name: str) -> str:
@@ -156,10 +177,12 @@ def phase(op_name: str) -> str:
     return "bwd" if "transpose(" in op_name else "fwd"
 
 
-def split(ops, modules, names) -> dict:
+def split(ops, modules, names, listed=()) -> dict:
     """Seconds of the device's ops by innermost scope and phase, by layer,
     and unscoped. ops: (HLO text, start, end); modules: (module name,
-    start, end) of the `XLA Modules` line; names: `op_names`'s map."""
+    start, end) of the `XLA Modules` line; names: `op_names`'s map;
+    listed: a configuration file's own scope names."""
+    pattern = scope_pattern(listed)
     modules = sorted(modules, key=lambda m: m[1])
     starts = [s for _, s, _ in modules]
 
@@ -186,7 +209,7 @@ def split(ops, modules, names) -> dict:
             inside = [c for c in containers if c[1] <= s < c[2]]
             if inside:
                 op_name = max(inside, key=lambda c: c[1])[0]
-        scope, layer = scopes_of(op_name)
+        scope, layer = scopes_of(op_name, pattern)
         if scope is None:
             unscoped += e - s
             continue
@@ -208,9 +231,10 @@ def input_wait(busy, spans) -> float:
 # ---------------------------------------------------------------------------
 # one traced run
 # ---------------------------------------------------------------------------
-def load(path: Path):
+def load(path: Path, span_prefixes=PROGRAM_SPANS):
     """(per-device ops, per-device module events, host spans with the
-    program's own, module op_names) of one `.xplane.pb`, in seconds."""
+    program's own that start with one of `span_prefixes`, module op_names)
+    of one `.xplane.pb`, in seconds."""
     from jax.profiler import ProfileData
     raw = path.read_bytes()
     pd = ProfileData.from_serialized_xspace(raw)
@@ -230,14 +254,17 @@ def load(path: Path):
                     (ev.name, ev.start_ns * 1e-9, ev.end_ns * 1e-9)
                     for ev in line.events
                     if ev.name in tracing.HOST_SPANS
-                    or ev.name.startswith(PROGRAM_SPANS))
+                    or ev.name.startswith(span_prefixes))
     return ops, mods, spans, op_names(raw)
 
 
-def reduce(path: Path) -> dict:
-    """The busiest device's scope split and the idle time inside the
-    program's spans, with the window as `tracing.reduce` takes it."""
-    ops, mods, spans, names = load(path)
+def reduce(path: Path, listed=(), span_prefixes=()) -> dict:
+    """The busiest device's scope split (with a configuration file's own
+    scope names, `listed`), the idle time inside the program's `data/*`
+    spans, and the seconds of each program span loaded (`data/*` and
+    `span_prefixes`), with the window as `tracing.reduce` takes it."""
+    prefixes = PROGRAM_SPANS + tuple(span_prefixes)
+    ops, mods, spans, names = load(path, prefixes)
     if not ops:
         return {}
     busy = {i: tracing.union((s, e) for _, s, e in o) for i, o in ops.items()}
@@ -247,7 +274,11 @@ def reduce(path: Path) -> dict:
         window = (min(s for _, s, _ in bench), max(e for _, _, e in bench))
     else:
         window = (busy[busiest][0][0], busy[busiest][-1][1])
-    out = split(ops[busiest], mods.get(busiest, []), names)
+    out = split(ops[busiest], mods.get(busiest, []), names, listed)
+    span_s: dict[str, float] = {}
+    for n, s, e in spans:
+        if n.startswith(prefixes):
+            span_s[n] = span_s.get(n, 0.0) + (e - s)
     begins = sorted(s for n, s, _ in bench if n == "bench/input")
     ends = sorted(e for n, _, e in bench if n == "bench/loss_read")
     out.update(step_s=[e - s for s, e in zip(begins, ends)],
@@ -255,7 +286,8 @@ def reduce(path: Path) -> dict:
                busy_s=tracing.length(busy[busiest]),
                input_wait_s=input_wait(busy[busiest], spans),
                program_spans=sum(1 for n, _, _ in spans
-                                 if n.startswith(PROGRAM_SPANS)))
+                                 if n.startswith(PROGRAM_SPANS)),
+               span_s=span_s)
     return out
 
 
@@ -283,7 +315,9 @@ def log_split(r: dict, steps: int) -> None:
 def of_run(run: dict) -> dict:
     """The split of the run's trace (the newest under the bench's trace
     directory, taken only if its window is the one the run's own reduction
-    read), worked out once per run and kept in the run's record."""
+    read), by the scope names and program span prefixes its configuration
+    file lists (the record's `config`), worked out once per run and kept in
+    the run's record."""
     if "scopes" in run:
         return run["scopes"]
     run["scopes"] = {}
@@ -294,7 +328,9 @@ def of_run(run: dict) -> dict:
                    key=lambda p: p.stat().st_mtime)
     if not files:
         return run["scopes"]
-    r = reduce(files[-1])
+    conf = run.get("config") or {}
+    r = reduce(files[-1], conf.get("scopes", ()),
+               conf.get("program_spans", ()))
     if r and abs(r["window_s"] - tr["window_s"]) <= 1e-9 * tr["window_s"]:
         log_split(r, run["trace_steps"])
         run["scopes"] = r
@@ -307,4 +343,15 @@ def ms_per_step(run: dict, prefixes: tuple[str, ...]):
     r = of_run(run)
     got = [v for k, v in r.get("by_layer", {}).items()
            if k.startswith(prefixes)]
+    return 1e3 * sum(got) / run["trace_steps"] if got else None
+
+
+def ms_per_scope(run: dict, names) -> float | None:
+    """Device ms per traced step under the scopes `names` (fixed ones, or
+    those a configuration file lists), all phases summed; an op under a
+    name nested in one counts to the nested name alone. None when no op
+    of the trace is under one."""
+    r = of_run(run)
+    got = [sum(ph.values()) for k, ph in r.get("by_scope", {}).items()
+           if k in names]
     return 1e3 * sum(got) / run["trace_steps"] if got else None

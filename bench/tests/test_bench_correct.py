@@ -39,14 +39,43 @@ def drive(c, fault=None):
                    devices=jax.devices()[:c["entry"]["chips"]], fault=fault)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_sound_run_reads_its_numbers(cell):
-    out = drive(tiny(cell))
+@pytest.fixture(scope="module", params=CELLS)
+def sound(request):
+    """(cell, a sound run of it at the tiny size), one run a cell."""
+    return request.param, drive(tiny(request.param))
+
+
+def test_sound_run_reads_its_numbers(sound):
+    cell, out = sound
     assert out["attempted"] >= 1 and out["failed"] == 0
     assert out["checks"]["input_mismatch"]["value"] == 0
     assert set(out["checks"]) == set(harness.cell(cell)["workload"]["limits"])
     for ch in out["checks"].values():
         assert ch["value"] is not None and ch["value"] >= 0
+
+
+def test_sound_run_records_the_cell(sound):
+    """The record carries what a new configuration's metric files read:
+    the configuration as run, the traffic, the program's ModelConfig, the
+    window's counters and the last step's outputs but its loss."""
+    import dataclasses
+    cell, out = sound
+    rec, c = out["record"], tiny(cell)
+    assert out["failed"] == 0
+    assert rec["config"] == c["config"]
+    assert rec["traffic"] == c["workload"]["traffic"]
+    drv = harness.load_module(c["driver"])
+    assert rec["model_config"] == dataclasses.asdict(
+        drv.model_config(c["config"]))
+    assert rec["model_config"]["d_model"] == c["config"]["hidden_size"]
+    assert set(rec["step_outputs"]) == {"gnorm"}
+    assert rec["step_outputs"]["gnorm"] > 0
+    assert rec["counters"] and all(isinstance(v, float)
+                                   for v in rec["counters"].values())
+    # the step was traced in set-up and never again in the window
+    traced = [k for k in rec["counters"]
+              if k.startswith("attention_path_total")]
+    assert traced and all(rec["counters"][k] == 0 for k in traced)
 
 
 @pytest.mark.parametrize("fault", ["frozen", "half_batch"])
